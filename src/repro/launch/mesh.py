@@ -31,11 +31,12 @@ def make_host_mesh(model: int = 1):
         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
-def make_elastic_mesh(target_model: int = 16):
+def make_elastic_mesh(target_model: int = 16, devices=None):
     """Largest (data, model) mesh from the available device pool: keeps the
     'model' extent fixed (TP degree is baked into layouts) and absorbs node
-    loss by shrinking 'data'."""
-    devs = jax.devices()
+    loss by shrinking 'data'.  ``devices`` defaults to every visible
+    device."""
+    devs = jax.devices() if devices is None else list(devices)
     n = len(devs)
     model = min(target_model, n)
     while n % model:
@@ -43,4 +44,4 @@ def make_elastic_mesh(target_model: int = 16):
     data = n // model
     return jax.make_mesh(
         (data, model), ("data", "model"),
-        axis_types=(jax.sharding.AxisType.Auto,) * 2)
+        axis_types=(jax.sharding.AxisType.Auto,) * 2, devices=devs)

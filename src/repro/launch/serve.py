@@ -2,21 +2,29 @@
 
   PYTHONPATH=src python -m repro.launch.serve --arch qwen1.5-0.5b --smoke \\
       --batch 4 --prompt-len 64 --gen 32
+
+Params and cache are built on the mesh (jit with ``out_shardings``); the
+prefill and decode steps are compiled before the clock starts, so the
+printed step times hold no compilation.
 """
 from __future__ import annotations
 
 import argparse
 import time
+from dataclasses import dataclass
+from functools import partial
+from typing import List
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_elastic_mesh
 from repro.models import lm
 from repro.serving.engine import make_serve_steps
-from repro.training.step import _abstract_init
+from repro.training.step import init_sharded
 
 
 def _plan_decode_mappings(cfg, B, P, G, deadline_s):
@@ -53,7 +61,65 @@ def _plan_decode_mappings(cfg, B, P, G, deadline_s):
           f"planned in {t_plan:.2f}s")
 
 
-def main(argv=None):
+@dataclass
+class ServeRun:
+    """What one :func:`generate` call produced, with its timings."""
+
+    batch: dict  # the prompt as served
+    tokens: jax.Array  # (B, G) greedy tokens; [:, 0] comes from prefill
+    logits: List[jax.Array]  # G x (B, vocab): prefill's last, then decode's
+    compile_s: float  # lower + compile of the prefill and decode steps
+    prefill_s: float
+    decode_s_per_step: float  # nan when G == 1
+
+
+def generate(cfg, mesh, params, specs, batch, gen: int, mode: str = "tp",
+             extra_len: int = 0) -> ServeRun:
+    """Prefill ``batch`` and decode ``gen - 1`` greedy steps on ``mesh``.
+
+    ``extra_len`` reserves cache slots for prompt positions that are not
+    tokens (a VLM's image embeddings).
+    """
+    B, P = batch["tokens"].shape
+    init_cache = partial(lm.init_cache, cfg, B, P + gen + extra_len)
+    prefill_step, decode_step, (_, _, cache_sh, _) = make_serve_steps(
+        cfg, mesh, specs, jax.eval_shape(init_cache), batch, mode=mode)
+    cache = jax.jit(init_cache, out_shardings=cache_sh)()
+    tok_abs = jax.ShapeDtypeStruct((B, 1), jnp.int32)
+
+    t0 = time.perf_counter()
+    prefill_step = prefill_step.lower(params, batch, cache).compile()
+    if gen > 1:
+        decode_step = decode_step.lower(params, tok_abs, cache).compile()
+    compile_s = time.perf_counter() - t0
+
+    t0 = time.perf_counter()
+    last, cache = prefill_step(params, batch, cache)
+    last.block_until_ready()
+    prefill_s = time.perf_counter() - t0
+
+    toks = jnp.argmax(last, -1)[:, None].astype(jnp.int32)
+    out_tokens, out_logits = [toks], [last]
+    t0 = time.perf_counter()
+    for _ in range(gen - 1):
+        logits, cache = decode_step(params, toks, cache)
+        toks = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
+        out_tokens.append(toks)
+        out_logits.append(logits)
+    jax.block_until_ready(toks)
+    t_decode = time.perf_counter() - t0
+    return ServeRun(
+        batch=batch, tokens=jnp.concatenate(out_tokens, axis=1), logits=out_logits,
+        compile_s=compile_s, prefill_s=prefill_s,
+        decode_s_per_step=t_decode / (gen - 1) if gen > 1 else float("nan"))
+
+
+def main(argv=None, devices=None):
+    """CLI entry point; ``devices`` (default: all visible) hold the mesh.
+
+    Returns ``(run, params, specs, mesh)`` so callers can check the
+    served logits against further steps on the same weights.
+    """
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -70,55 +136,37 @@ def main(argv=None):
     ap.add_argument("--map-deadline-ms", type=float, default=50.0,
                     help="per-query deadline for --map-service (ms)")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
-    mesh = make_elastic_mesh(target_model=args.model_parallel)
+    mesh = make_elastic_mesh(target_model=args.model_parallel,
+                             devices=devices)
     B, P, G = args.batch, args.prompt_len, args.gen
 
     if args.map_service:
         _plan_decode_mappings(cfg, B, P, G, args.map_deadline_ms / 1e3)
 
-    params_abs, specs = _abstract_init(cfg, jax.random.PRNGKey(0))
-    cache_abs = jax.eval_shape(lambda: lm.init_cache(cfg, B, P + G))
     rng = np.random.default_rng(0)
     batch = {"tokens": jnp.asarray(
         rng.integers(0, cfg.vocab, (B, P)), jnp.int32)}
+    extra_len = 0
     if cfg.family == "vlm":
         batch["embeds"] = jnp.asarray(
             rng.normal(size=(B, 8, cfg.frontend_dim)), jnp.float32)
-        cache_abs = jax.eval_shape(lambda: lm.init_cache(cfg, B, P + G + 8))
+        extra_len = 8
     if cfg.family == "audio":
         batch["enc_frames"] = jnp.asarray(
             rng.normal(size=(B, P, cfg.frontend_dim)), jnp.float32)
 
-    prefill_step, decode_step, _ = make_serve_steps(
-        cfg, mesh, specs, cache_abs, batch, mode=args.mode)
-
-    params, _ = lm.init(cfg, jax.random.PRNGKey(0))
-    cache = jax.eval_shape(lambda: 0)  # placeholder
-    cache = lm.init_cache(cfg, B, P + G + (8 if cfg.family == "vlm" else 0))
-
-    t0 = time.perf_counter()
-    last, cache = prefill_step(params, batch, cache)
-    last.block_until_ready()
-    t_prefill = time.perf_counter() - t0
-
-    toks = jnp.argmax(last, -1)[:, None].astype(jnp.int32)
-    out_tokens = [toks]
-    t0 = time.perf_counter()
-    for _ in range(G - 1):
-        logits, cache = decode_step(params, toks, cache)
-        toks = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
-        out_tokens.append(toks)
-    jax.block_until_ready(toks)
-    t_decode = time.perf_counter() - t0
-
-    gen = jnp.concatenate(out_tokens, axis=1)
-    print(f"prefill {B}x{P}: {t_prefill*1e3:.0f}ms  "
-          f"decode {G-1} steps: {t_decode*1e3:.0f}ms "
-          f"({(G-1)*B/max(t_decode,1e-9):.1f} tok/s)")
-    print("sample:", np.asarray(gen[0][:16]))
-    return np.asarray(gen)
+    params, specs, _ = init_sharded(cfg, None, mesh, mode=args.mode)
+    run = generate(cfg, mesh, params, specs, batch, G, mode=args.mode,
+                   extra_len=extra_len)
+    print(f"compile {run.compile_s:.2f}s  prefill {B}x{P}: "
+          f"{run.prefill_s*1e3:.0f}ms  decode {G-1} steps: "
+          f"{run.decode_s_per_step*1e3:.2f}ms/step "
+          f"({B/max(run.decode_s_per_step, 1e-9):.1f} tok/s)")
+    print("sample:", np.asarray(run.tokens[0][:16]))
+    return run, params, specs, mesh
 
 
 if __name__ == "__main__":

@@ -27,6 +27,7 @@ import numpy as np
 from repro.checkpoint.manager import CheckpointManager
 from repro.configs import get_config
 from repro.data.pipeline import DataConfig, SyntheticTokens
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_elastic_mesh
 from repro.optim.adamw import OptConfig
 from repro.training.step import init_sharded, make_train_step, _abstract_init
@@ -51,6 +52,7 @@ def main(argv=None):
     ap.add_argument("--straggler-factor", type=float, default=3.0)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     oc = OptConfig(kind=args.optimizer, lr=args.lr,

@@ -66,7 +66,8 @@ def build_parser() -> argparse.ArgumentParser:
                    "so every bench starts cold)")
     b.add_argument("--measure", action="store_true",
                    help="also lower one matmul + one flash-attention "
-                   "shape to Pallas via service tiles and time them")
+                   "shape to Pallas via service tiles and time them on "
+                   "the TPU (exits 2 on a host without one)")
     b.add_argument("--json", default=None, metavar="PATH",
                    help="dump the full report as JSON")
     b.add_argument("--gate-hit-p99-ms", type=float, default=None,
@@ -87,6 +88,14 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def _bench(args) -> int:
+    if args.measure:
+        import jax
+
+        platform = jax.devices()[0].platform
+        if platform != "tpu":
+            print(f"--measure times the Pallas kernels on a TPU; this "
+                  f"host's JAX platform is {platform!r}", file=sys.stderr)
+            return 2
     cfg = get_config(args.config, smoke=args.fast)
     arch = ACCEL[args.accel]()
     cache_dir = args.cache_dir or tempfile.mkdtemp(prefix="tcm-serve-")

@@ -1,26 +1,26 @@
-"""Close the loop: served mappings -> Pallas BlockSpec tiles -> walltime.
+"""Served mappings -> Pallas BlockSpec tiles -> kernel walltime.
 
-The service's answers are *modeled*-optimal; this module checks them
-against the silicon (or, on CPU, the Pallas interpreter).  A mapping for
-the block-unit VMEM arch (``core.autotile``) is requested **through the
-service** — exercising the full hot path: bucketing, coalescing, hot
-index — and its per-rank tile products become the kernel's BlockSpec
-blocks.  The kernel is then timed (min over repeats, after a compile
-warmup, with ``block_until_ready``) against the default 128-cube tiling,
-and the report carries the measured-vs-modeled ratio.
+The service's answers are *modeled*-optimal; this module times the kernels
+they tile.  A mapping for the block-unit VMEM arch (``core.autotile``) is
+requested **through the service** — exercising the full hot path:
+bucketing, coalescing, hot index — and the grid blocks it implies become
+the kernel's BlockSpec blocks.  The kernel is then timed (min over
+repeats, after a compile warmup, with ``block_until_ready``) against the
+default 128-cube tiling, and the report carries the measured-vs-modeled
+ratio.
 
-Interpret-mode caveat (stated in every report row): off-TPU the kernels
-run under the Pallas interpreter, so absolute times are simulation
-walltime, not silicon — the *relative* tcm-vs-default comparison is still
-meaningful (same interpreter, same work, different schedule), and on a
-real TPU the same code measures silicon.
+The kernels compile for the TPU unless the caller passes
+``interpret=True``; a row timed in interpret mode says so in its
+``interpret`` field, and its times are the Pallas interpreter's on the
+host, not a device's.
 """
 from __future__ import annotations
 
 import time
 from typing import Optional, Tuple
 
-from repro.core.autotile import MXU, _tile_products, _v5e_core
+from repro.core.autotile import (MXU, VMEM_LIMIT_BYTES, _v5e_core,
+                                 grid_tiles, matmul_vmem_bytes)
 from repro.core.einsum import matmul
 
 from .request import MapRequest
@@ -31,7 +31,7 @@ __all__ = ["service_matmul_tiles", "measure_matmul",
 
 
 def service_matmul_tiles(service: MappingService, M: int, K: int, N: int,
-                         *, vmem_bytes: int = 16 * 2 ** 20,
+                         *, vmem_bytes: int = VMEM_LIMIT_BYTES,
                          word_bytes: int = 2,
                          deadline_s: Optional[float] = None,
                          ) -> Tuple[Tuple[int, int, int], "object"]:
@@ -50,9 +50,10 @@ def service_matmul_tiles(service: MappingService, M: int, K: int, N: int,
     resp = service.map(MapRequest(einsum=ein, arch=arch,
                                   objective="latency",
                                   deadline_s=deadline_s))
-    t = _tile_products(resp.result, resp.served_einsum)
-    tiles = (min(M, t["m"] * MXU), min(K, t["k"] * MXU),
-             min(N, t["n"] * MXU))
+    tiles, _ = grid_tiles(resp.result, resp.served_einsum, M, K, N)
+    if matmul_vmem_bytes(*tiles, word_bytes) > vmem_bytes:
+        raise ValueError(f"served tile {tiles} for {M}x{K}x{N} exceeds "
+                         f"{vmem_bytes} bytes of VMEM")
     return tiles, resp
 
 
@@ -69,8 +70,9 @@ def _time_best(fn, repeats: int = 3) -> float:
 
 def measure_matmul(service: MappingService, M: int = 512, K: int = 512,
                    N: int = 512, *, repeats: int = 3,
-                   interpret: Optional[bool] = None) -> dict:
-    """Time the service-tiled Pallas matmul vs the default 128-cube tiling.
+                   interpret: bool = False) -> dict:
+    """Time the service-tiled bf16 Pallas matmul vs the default 128-cube
+    tiling.
 
     Shapes should be MXU-aligned powers of two (the service's buckets then
     pass them through unchanged and the tiles always divide the dims).
@@ -79,15 +81,12 @@ def measure_matmul(service: MappingService, M: int = 512, K: int = 512,
     import jax.numpy as jnp
 
     from repro.kernels.matmul import matmul_pallas
-    from repro.kernels.ops import _interpret_default
 
-    if interpret is None:
-        interpret = _interpret_default()
     (bm, bk, bn), resp = service_matmul_tiles(service, M, K, N)
     key = jax.random.PRNGKey(0)
     ka, kb_ = jax.random.split(key)
-    a = jax.random.normal(ka, (M, K), dtype=jnp.float32)
-    b = jax.random.normal(kb_, (K, N), dtype=jnp.float32)
+    a = jax.random.normal(ka, (M, K), dtype=jnp.bfloat16)
+    b = jax.random.normal(kb_, (K, N), dtype=jnp.bfloat16)
 
     t_tcm = _time_best(
         lambda: matmul_pallas(a, b, bm=bm, bk=bk, bn=bn,
@@ -118,7 +117,7 @@ def measure_flash_attention(service: MappingService, B: int = 1,
                             H: int = 4, Sq: int = 256, Sk: int = 256,
                             Dh: int = 128, *, causal: bool = False,
                             repeats: int = 3,
-                            interpret: Optional[bool] = None) -> dict:
+                            interpret: bool = False) -> dict:
     """Time flash attention with service-chosen (bq, bk) vs default 128s.
 
     The score matmul ``S = Q @ K^T`` (per head: M=Sq, K=Dh, N=Sk) drives
@@ -130,17 +129,14 @@ def measure_flash_attention(service: MappingService, B: int = 1,
     import jax.numpy as jnp
 
     from repro.kernels.flash_attention import flash_attention_pallas
-    from repro.kernels.ops import _interpret_default
 
-    if interpret is None:
-        interpret = _interpret_default()
     (bm, _, bn), resp = service_matmul_tiles(service, Sq, Dh, Sk)
     bq, bkv = min(bm, Sq), min(bn, Sk)
     key = jax.random.PRNGKey(1)
     kq, kk, kv = jax.random.split(key, 3)
-    q = jax.random.normal(kq, (B, Sq, H, Dh), dtype=jnp.float32)
-    k = jax.random.normal(kk, (B, Sk, H, Dh), dtype=jnp.float32)
-    v = jax.random.normal(kv, (B, Sk, H, Dh), dtype=jnp.float32)
+    q = jax.random.normal(kq, (B, Sq, H, Dh), dtype=jnp.bfloat16)
+    k = jax.random.normal(kk, (B, Sk, H, Dh), dtype=jnp.bfloat16)
+    v = jax.random.normal(kv, (B, Sk, H, Dh), dtype=jnp.bfloat16)
 
     t_tcm = _time_best(
         lambda: flash_attention_pallas(q, k, v, causal=causal, bq=bq,

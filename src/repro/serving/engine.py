@@ -16,6 +16,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.distributed.sharding import shardings_for
 from repro.models import lm
 from repro.models.config import ModelConfig
+from repro.training.step import _abstract_init
 
 
 def _axis_size(mesh: Mesh, names) -> int:
@@ -81,7 +82,10 @@ def batch_shardings(mesh: Mesh, batch_abstract):
 
 def make_serve_steps(cfg: ModelConfig, mesh: Mesh, specs, cache_abstract,
                      batch_abstract, mode: str = "tp"):
-    param_sh = shardings_for(specs, mesh, mode)  # serve params: see caller
+    # params placed as training.step.init_sharded places them: each
+    # sharding gated on the param's shape dividing the mesh axes
+    params_abs, _ = _abstract_init(cfg, jax.random.PRNGKey(0))
+    param_sh = shardings_for(specs, mesh, mode, like=params_abs)
     cache_sh = cache_shardings(cfg, cache_abstract, mesh)
     batch_sh = batch_shardings(mesh, batch_abstract)
 

@@ -1,0 +1,102 @@
+"""Compile the served path's kernels and decode step for a described v5e.
+
+Nothing runs: the TPU compiler accepts or refuses each program for a chip
+that is described, not attached.  It refuses what interpret mode cannot
+see: blocks that exceed scoped VMEM, slices off the tiling, programs that
+do not fit the device.  The topology is described inside the fixture, so
+that importing this file loads no TPU library.
+"""
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.core.autotile import tcm_matmul_tiles
+from repro.kernels.flash_attention import flash_attention_pallas
+from repro.kernels.matmul import matmul_pallas
+
+# qwen1.5-0.5b projections at 8x1024 prefill tokens and 8 decode tokens:
+# q/k/v/o, gate/up, down, lm_head
+QWEN_MATMULS = [(M, K, N) for M in (8 * 1024, 8)
+                for K, N in ((1024, 1024), (1024, 2816), (2816, 1024),
+                             (1024, 151936))]
+HBM_BYTES = 16 * 10 ** 9
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    # a compile for a described chip cannot be read back from the cache
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # no TPU compiler in this installation
+            jax.config.update("jax_enable_compilation_cache", enabled)
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield topo.devices[0]
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, one_chip, *shapes):
+    dev = SingleDeviceSharding(one_chip)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=dev) for s, d in shapes]
+    return jax.jit(fn).lower(*args).compile()
+
+
+@pytest.mark.parametrize("M,K,N", QWEN_MATMULS)
+def test_matmul_kernel_tcm_tiles_compile(one_chip, M, K, N):
+    bm, bk, bn = tcm_matmul_tiles(M, K, N, word_bytes=2)
+    compiled = _compile(partial(matmul_pallas, bm=bm, bk=bk, bn=bn),
+                        one_chip, ((M, K), jnp.bfloat16),
+                        ((K, N), jnp.bfloat16))
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_flash_attention_qwen_heads_compile(one_chip):
+    B, S, H, Dh = 8, 1024, 16, 64
+    bm, _, bn = tcm_matmul_tiles(S, Dh, S, word_bytes=2)
+    qkv = ((B, S, H, Dh), jnp.bfloat16)
+    compiled = _compile(partial(flash_attention_pallas, causal=True,
+                                bq=min(bm, S), bk=min(bn, S)),
+                        one_chip, qkv, qkv, qkv)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_qwen_decode_step_compiles_on_one_chip(one_chip):
+    """The served decode step at published widths fits one v5e."""
+    from repro.launch.mesh import make_elastic_mesh
+    from repro.models import lm
+    from repro.serving.engine import make_serve_steps
+    from repro.training.step import _abstract_init
+
+    cfg = get_config("qwen1.5-0.5b")
+    B, P, G = 8, 1024, 32
+    mesh = make_elastic_mesh(target_model=1, devices=[one_chip])
+    params_abs, specs = _abstract_init(cfg, jax.random.PRNGKey(0))
+    cache_abs = jax.eval_shape(lambda: lm.init_cache(cfg, B, P + G))
+    batch_abs = {"tokens": jax.ShapeDtypeStruct((B, P), jnp.int32)}
+    _, decode, (param_sh, _, cache_sh, tok_sh) = make_serve_steps(
+        cfg, mesh, specs, cache_abs, batch_abs)
+
+    def placed(tree, shardings):
+        return jax.tree.map(
+            lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+            tree, shardings)
+
+    compiled = decode.lower(
+        placed(params_abs, param_sh),
+        jax.ShapeDtypeStruct((B, 1), jnp.int32, sharding=tok_sh),
+        placed(cache_abs, cache_sh)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES

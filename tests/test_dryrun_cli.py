@@ -17,7 +17,8 @@ def test_dryrun_cell_compiles(tmp_path, mesh):
            "--arch", "mamba2-130m", "--shape", "decode_32k",
            "--mesh", mesh, "--out", str(tmp_path)]
     r = subprocess.run(cmd, capture_output=True, text=True, timeout=420,
-                       env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"})
+                       env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
+                            "JAX_PLATFORMS": "cpu"})
     assert r.returncode == 0, r.stderr[-1500:]
     out = list(tmp_path.glob("*.json"))
     assert len(out) == 1

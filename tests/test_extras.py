@@ -4,7 +4,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.autotile import tcm_matmul_tiles
+from repro.core.autotile import (VMEM_LIMIT_BYTES, matmul_vmem_bytes,
+                                 tcm_matmul_tiles)
 from repro.core.shard_planner import plan_matmul
 from repro.distributed.compression import (compress_decompress,
                                            init_error_feedback, quantized_psum)
@@ -35,8 +36,7 @@ def test_compression_error_feedback_converges():
 
 
 def test_quantized_psum_matches_psum():
-    from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax.sharding import PartitionSpec as P
     mesh = jax.make_mesh((1,), ("d",),
                          axis_types=(jax.sharding.AxisType.Auto,))
     x = jnp.asarray(np.random.default_rng(2).normal(size=(256,)), jnp.float32)
@@ -44,7 +44,7 @@ def test_quantized_psum_matches_psum():
     def f(x):
         return quantized_psum(x, "d")
 
-    out = shard_map(f, mesh=mesh, in_specs=P("d"), out_specs=P("d"))(x)
+    out = jax.shard_map(f, mesh=mesh, in_specs=P("d"), out_specs=P("d"))(x)
     np.testing.assert_allclose(np.asarray(out), np.asarray(x), atol=2e-2,
                                rtol=2e-2)
 
@@ -62,8 +62,25 @@ def test_shard_planner_small_model_prefers_data_parallel():
     assert plan.data_factor["m"] * plan.model_factor["m"] >= 16
 
 
-def test_autotile_alignment_and_capacity():
-    bm, bk, bn = tcm_matmul_tiles(4096, 4096, 4096)
-    assert bm % 128 == 0 and bk % 128 == 0 and bn % 128 == 0
-    # working set fits the modeled VMEM
-    assert 2 * (bm * bk + bk * bn + bm * bn) <= 16 * 2 ** 20
+# qwen1.5-0.5b projections at 8x1024 prefill and 8 decode tokens, + 4096^3
+AUTOTILE_SHAPES = [(M, K, N) for M in (8 * 1024, 8)
+                   for K, N in ((1024, 1024), (1024, 2816), (2816, 1024),
+                                (1024, 151936))] + [(4096, 4096, 4096)]
+
+
+@pytest.mark.parametrize("word_bytes", [2, 4])
+@pytest.mark.parametrize("M,K,N", AUTOTILE_SHAPES)
+def test_autotile_alignment_and_capacity(M, K, N, word_bytes):
+    bm, bk, bn = tcm_matmul_tiles(M, K, N, word_bytes=word_bytes)
+    for tile, dim in ((bm, M), (bk, K), (bn, N)):
+        # MXU-aligned, or one block of a dim below the MXU; divides the dim
+        assert tile % 128 == 0 or tile == dim < 128
+        assert dim % tile == 0
+    # everything matmul_pallas allocates fits the compiler's scoped VMEM
+    assert matmul_vmem_bytes(bm, bk, bn, word_bytes) <= VMEM_LIMIT_BYTES
+
+
+def test_autotile_raises_when_no_tile_fits():
+    # three 128x128 bf16 blocks, double-buffered, exceed 64 KiB
+    with pytest.raises(ValueError, match="no matmul_pallas tile"):
+        tcm_matmul_tiles(1024, 1024, 1024, vmem_bytes=64 * 1024)

@@ -131,7 +131,24 @@ def test_train_cli_smoke(tmp_path):
 
 def test_serve_cli_smoke():
     from repro.launch import serve as serve_mod
-    gen = serve_mod.main(["--arch", "qwen1.5-0.5b", "--smoke",
-                          "--batch", "2", "--prompt-len", "16",
-                          "--gen", "4"])
-    assert gen.shape == (2, 4)
+    run, *_ = serve_mod.main(["--arch", "qwen1.5-0.5b", "--smoke",
+                              "--batch", "2", "--prompt-len", "16",
+                              "--gen", "4"])
+    assert run.tokens.shape == (2, 4)
+
+
+def test_compile_cache_dir(tmp_path, monkeypatch):
+    from repro.launch.compile_cache import DEFAULT_DIR, use_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        # a directory named by the environment is JAX's to read
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert use_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+        # else one fixed directory at the checkout root
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert use_compile_cache() == str(DEFAULT_DIR)
+        assert jax.config.jax_compilation_cache_dir == str(DEFAULT_DIR)
+        assert (DEFAULT_DIR.parent / "src" / "repro").is_dir()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -286,3 +286,13 @@ def test_loadgen_smoke(tmp_path):
     assert report["coalesce_ratio"] == pytest.approx(0.75)
     assert report["deadline_met_ratio"] == 1.0
     assert report["service"]["requests"] >= 16
+
+
+def test_bench_measure_refuses_host_without_tpu(capsys):
+    import jax
+
+    from repro.serve_map.__main__ import main
+    if jax.devices()[0].platform == "tpu":
+        pytest.skip("this host has a TPU: --measure times it")
+    assert main(["bench", "--fast", "--measure"]) == 2
+    assert "TPU" in capsys.readouterr().err
