@@ -1,4 +1,4 @@
-"""Post-SPMD HLO text analyzer for the roofline (launch/roofline.py).
+"""Post-SPMD HLO text analyzer for the dry run's roofline inputs.
 
 ``compiled.cost_analysis()`` on the CPU backend neither scales while-loop
 bodies by trip count nor separates collectives, so we parse the optimized
@@ -14,7 +14,7 @@ HLO text ourselves:
                 trip counts (the assignment's prescribed method).
 
 All numbers are PER DEVICE (post-SPMD shapes are shard shapes), which is
-exactly the denominator-free form the roofline terms need.
+the form the dry run reports (``launch/dryrun.py``).
 """
 from __future__ import annotations
 

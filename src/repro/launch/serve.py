@@ -23,6 +23,7 @@ from repro.configs import get_config
 from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_elastic_mesh
 from repro.models import lm
+from repro.obs.serving import WATCH
 from repro.serving.engine import make_serve_steps
 from repro.training.step import init_sharded
 
@@ -137,6 +138,7 @@ def main(argv=None, devices=None):
                     help="per-query deadline for --map-service (ms)")
     args = ap.parse_args(argv)
     use_compile_cache()
+    WATCH.install()  # counted below; `gc` spans in any profile taken
 
     cfg = get_config(args.arch, smoke=args.smoke)
     mesh = make_elastic_mesh(target_model=args.model_parallel,
@@ -159,13 +161,18 @@ def main(argv=None, devices=None):
             rng.normal(size=(B, P, cfg.frontend_dim)), jnp.float32)
 
     params, specs, _ = init_sharded(cfg, None, mesh, mode=args.mode)
+    gc_before = WATCH.snapshot()
     run = generate(cfg, mesh, params, specs, batch, G, mode=args.mode,
                    extra_len=extra_len)
+    gc_run = WATCH.snapshot() - gc_before
     print(f"compile {run.compile_s:.2f}s  prefill {B}x{P}: "
           f"{run.prefill_s*1e3:.0f}ms  decode {G-1} steps: "
           f"{run.decode_s_per_step*1e3:.2f}ms/step "
           f"({B/max(run.decode_s_per_step, 1e-9):.1f} tok/s)")
     print("sample:", np.asarray(run.tokens[0][:16]))
+    print(f"gc: {'/'.join(map(str, gc_run.collections))} collections "
+          f"(generations 0/1/2), {gc_run.pause_s * 1e3:.3f} ms paused, "
+          "compile included")
     return run, params, specs, mesh
 
 

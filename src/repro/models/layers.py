@@ -15,6 +15,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.distributed.sharding import constrain, constrain_any
+from repro.obs.serving import ATTEND, ATTN_OUT, KV_WRITE, MLP, NORM, QKV
 
 Params = Dict
 Specs = Dict
@@ -33,6 +34,7 @@ def rmsnorm_params(d: int, dtype) -> Tuple[Params, Specs]:
     return {"scale": jnp.ones((d,), dtype)}, {"scale": ("embed",)}
 
 
+@jax.named_scope(NORM)
 def rmsnorm(p: Params, x: jnp.ndarray, eps: float = 1e-6) -> jnp.ndarray:
     dt = x.dtype
     x = x.astype(jnp.float32)
@@ -40,6 +42,7 @@ def rmsnorm(p: Params, x: jnp.ndarray, eps: float = 1e-6) -> jnp.ndarray:
     return (x * p["scale"].astype(jnp.float32)).astype(dt)
 
 
+@jax.named_scope(QKV)  # scoped with the q/k/v projections it rotates
 def rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
     """x: (..., S, H, Dh); positions: (..., S)."""
     dh = x.shape[-1]
@@ -253,6 +256,7 @@ def _flash_fwd(cfgt, q, k, v, q_off_f, kv_valid_f):
 _flash.defvjp(_flash_fwd, _flash_bwd_impl)
 
 
+@jax.named_scope(ATTEND)
 def flash_attention(q, k, v, *, causal: bool, window: int = 0,
                     q_offset=0, q_chunk: int = 512, kv_chunk: int = 512,
                     kv_valid=None):
@@ -288,6 +292,7 @@ def flash_attention(q, k, v, *, causal: bool, window: int = 0,
     return out.reshape(B, nq * q_chunk, Hq, Dh)[:, :Sq].astype(q.dtype)
 
 
+@jax.named_scope(QKV)
 def _qkv(cfg, p, x, src):
     B, S, _ = x.shape
     dt = cfg.jdtype
@@ -348,10 +353,11 @@ def attention_block(cfg, p: Params, x, positions, *, cache=None,
         if ring:
             if S == 1:
                 slot = idx % window
-                ck = lax.dynamic_update_slice(cache["k"], k.astype(dt),
-                                              (0, slot, 0, 0))
-                cv = lax.dynamic_update_slice(cache["v"], v.astype(dt),
-                                              (0, slot, 0, 0))
+                with jax.named_scope(KV_WRITE):
+                    ck = lax.dynamic_update_slice(cache["k"], k.astype(dt),
+                                                  (0, slot, 0, 0))
+                    cv = lax.dynamic_update_slice(cache["v"], v.astype(dt),
+                                                  (0, slot, 0, 0))
                 filled = jnp.minimum(idx + 1, window)
                 out = flash_attention(q, ck, cv, causal=False,
                                       kv_valid=filled)
@@ -363,21 +369,25 @@ def attention_block(cfg, p: Params, x, positions, *, cache=None,
                                       q_offset=0)
                 last = jnp.arange(S - window, S)
                 slots = last % window
-                ck = jnp.zeros_like(cache["k"]).at[:, slots].set(
-                    k[:, last].astype(dt))
-                cv = jnp.zeros_like(cache["v"]).at[:, slots].set(
-                    v[:, last].astype(dt))
+                with jax.named_scope(KV_WRITE):
+                    ck = jnp.zeros_like(cache["k"]).at[:, slots].set(
+                        k[:, last].astype(dt))
+                    cv = jnp.zeros_like(cache["v"]).at[:, slots].set(
+                        v[:, last].astype(dt))
             new_cache = {"k": ck, "v": cv, "idx": idx + S}
         else:
-            ck = lax.dynamic_update_slice(cache["k"], k.astype(dt),
-                                          (0, idx, 0, 0))
-            cv = lax.dynamic_update_slice(cache["v"], v.astype(dt),
-                                          (0, idx, 0, 0))
+            with jax.named_scope(KV_WRITE):
+                ck = lax.dynamic_update_slice(cache["k"], k.astype(dt),
+                                              (0, idx, 0, 0))
+                cv = lax.dynamic_update_slice(cache["v"], v.astype(dt),
+                                              (0, idx, 0, 0))
             new_cache = {"k": ck, "v": cv, "idx": idx + S}
             out = flash_attention(q, ck, cv, causal=True, window=window,
                                   q_offset=idx, kv_valid=idx + S)
     out = out.reshape(B, S, cfg.q_dim)
-    return out @ p["wo"].astype(dt), new_cache
+    with jax.named_scope(ATTN_OUT):
+        out = out @ p["wo"].astype(dt)
+    return out, new_cache
 
 
 def cross_attention_cached(cfg, p: Params, x, ck, cv):
@@ -422,6 +432,7 @@ def mlp_params(cfg, key) -> Tuple[Params, Specs]:
     return p, s
 
 
+@jax.named_scope(MLP)
 def mlp(cfg, p: Params, x):
     dt = cfg.jdtype
     g = jax.nn.silu(constrain(x @ p["wg"].astype(dt),
@@ -448,6 +459,7 @@ def moe_params(cfg, key) -> Tuple[Params, Specs]:
     return p, s
 
 
+@jax.named_scope(MLP)
 def moe(cfg, p: Params, x, rng: Optional[jax.Array] = None):
     """Top-k token-choice MoE with fixed expert capacity (dropping).
 

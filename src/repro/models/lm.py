@@ -28,6 +28,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.distributed.sharding import constrain
+from repro.obs.serving import EMBED, LAYERS, LM_HEAD
 
 from .config import ModelConfig
 from .layers import (_init, attention_block, attention_params,
@@ -219,32 +220,36 @@ def _apply_group(cfg, kinds, count, group_params, x, positions,
         body = jax.checkpoint(
             body, policy=jax.checkpoint_policies.nothing_saveable)
 
-    aux0 = jnp.zeros((), jnp.float32)
-    if count == 1:
-        lp = jax.tree.map(lambda a: a[0], group_params)
-        lc = (None if caches is None
-              else jax.tree.map(lambda a: a[0], caches))
-        (x, aux), nc = body((x, aux0), (lp, lc))
-        nc = None if nc is None else jax.tree.map(lambda a: a[None], nc)
-        return x, nc, aux
-
-    if cfg.unroll_layers:
-        aux = aux0
-        ncs = []
-        for i in range(count):
-            lp = jax.tree.map(lambda a: a[i], group_params)
+    # the stack of layers, however it is run
+    with jax.named_scope(LAYERS):
+        aux0 = jnp.zeros((), jnp.float32)
+        if count == 1:
+            lp = jax.tree.map(lambda a: a[0], group_params)
             lc = (None if caches is None
-                  else jax.tree.map(lambda a: a[i], caches))
-            (x, aux), nc = body((x, aux), (lp, lc))
-            ncs.append(nc)
-        new_caches = (None if caches is None else
-                      jax.tree.map(lambda *xs: jnp.stack(xs), *ncs))
+                  else jax.tree.map(lambda a: a[0], caches))
+            (x, aux), nc = body((x, aux0), (lp, lc))
+            nc = None if nc is None else jax.tree.map(lambda a: a[None], nc)
+            return x, nc, aux
+
+        if cfg.unroll_layers:
+            aux = aux0
+            ncs = []
+            for i in range(count):
+                lp = jax.tree.map(lambda a: a[i], group_params)
+                lc = (None if caches is None
+                      else jax.tree.map(lambda a: a[i], caches))
+                (x, aux), nc = body((x, aux), (lp, lc))
+                ncs.append(nc)
+            new_caches = (None if caches is None else
+                          jax.tree.map(lambda *xs: jnp.stack(xs), *ncs))
+            return x, new_caches, aux
+
+        (x, aux), new_caches = lax.scan(body, (x, aux0),
+                                        (group_params, caches))
         return x, new_caches, aux
 
-    (x, aux), new_caches = lax.scan(body, (x, aux0), (group_params, caches))
-    return x, new_caches, aux
 
-
+@jax.named_scope(EMBED)
 def _embed(cfg, params, tokens):
     e = params["embed"]["tok"].astype(cfg.jdtype)[tokens]
     return constrain(e * math.sqrt(cfg.d_model), ("batch", "act_seq", None))
@@ -256,8 +261,9 @@ def _head(cfg, params, x):
         w = params["embed"]["tok"].astype(cfg.jdtype).T
     else:
         w = params["lm_head"].astype(cfg.jdtype)
-    return constrain((x @ w).astype(jnp.float32),
-                     ("batch", "act_seq", "vocab"))
+    with jax.named_scope(LM_HEAD):
+        logits = (x @ w).astype(jnp.float32)
+    return constrain(logits, ("batch", "act_seq", "vocab"))
 
 
 def _encoder_out(cfg, params, enc_frames, caches=None):

@@ -1,0 +1,98 @@
+"""The served step's trace vocabulary: device scopes and the ``gc`` span.
+
+Device scopes are ``jax.named_scope`` names the model puts around each
+layer kind of the served step.  XLA keeps them in every instruction's
+``metadata={op_name=...}``, so a profiler trace of the step can be joined
+with the compiled program to say which layer kind each device op belongs
+to.  They are metadata only: the compiled program is otherwise the same.
+
+  embed     the token embedding lookup (``models/lm.py _embed``)
+  norm      every RMSNorm
+  qkv       the q/k/v projections and RoPE
+  kv_write  writes of the new K/V into the cache
+  attend    every ``flash_attention`` call
+  attn_out  the attention output projection
+  mlp       the MLP or MoE
+  lm_head   the output projection to the vocabulary
+
+``LAYERS`` scopes the stack of layers (the ``lax.scan`` over stacked
+parameters, or its unrolled loop), so ops of the stack that no leaf
+scope claims, such as the scan's slices of the stacked cache, are still
+told apart from ops outside it.
+
+:class:`GcWatch` counts Python's garbage collections and their pauses, and
+puts each collection on the profiler's host clock as a ``gc`` span, beside
+the spans of whoever drives the served step.
+
+Nothing here imports JAX at import time.
+"""
+from __future__ import annotations
+
+import gc
+import time
+from dataclasses import dataclass
+from typing import Optional, Tuple
+
+SCOPES = EMBED, NORM, QKV, KV_WRITE, ATTEND, ATTN_OUT, MLP, LM_HEAD = (
+    "embed", "norm", "qkv", "kv_write", "attend", "attn_out", "mlp",
+    "lm_head")
+LAYERS = "layers"
+GC_SPAN = "gc"
+
+
+@dataclass(frozen=True)
+class GcCounts:
+    """Collections per generation and their total pause, in seconds."""
+
+    collections: Tuple[int, int, int] = (0, 0, 0)
+    pause_s: float = 0.0
+
+    def __sub__(self, earlier: "GcCounts") -> "GcCounts":
+        return GcCounts(
+            tuple(a - b for a, b in zip(self.collections,
+                                        earlier.collections)),
+            self.pause_s - earlier.pause_s)
+
+
+class GcWatch:
+    """A ``gc.callbacks`` hook: counts and times every collection since
+    :meth:`install`, each inside a ``jax.profiler.TraceAnnotation``
+    named ``gc``.  With the profiler off a collection costs one Python
+    call more."""
+
+    def __init__(self):
+        self._collections = [0, 0, 0]
+        self._pause_s = 0.0
+        self._t0: Optional[float] = None
+        self._span = None
+        self._annotation = None
+
+    def install(self) -> "GcWatch":
+        """Hook into ``gc.callbacks``, once however often it is called."""
+        if self._callback not in gc.callbacks:
+            from jax.profiler import TraceAnnotation
+
+            self._annotation = TraceAnnotation
+            gc.callbacks.append(self._callback)
+        return self
+
+    def uninstall(self) -> None:
+        if self._callback in gc.callbacks:
+            gc.callbacks.remove(self._callback)
+
+    def snapshot(self) -> GcCounts:
+        return GcCounts(tuple(self._collections), self._pause_s)
+
+    def _callback(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._span = self._annotation(GC_SPAN)
+            self._span.__enter__()
+            self._t0 = time.perf_counter()
+        elif self._t0 is not None:  # "stop" of a collection seen starting
+            self._pause_s += time.perf_counter() - self._t0
+            self._collections[info["generation"]] += 1
+            self._span.__exit__(None, None, None)
+            self._t0 = self._span = None
+
+
+WATCH = GcWatch()  # the serving process's watch: install it once, read it

@@ -89,10 +89,12 @@ def make_serve_steps(cfg: ModelConfig, mesh: Mesh, specs, cache_abstract,
     cache_sh = cache_shardings(cfg, cache_abstract, mesh)
     batch_sh = batch_shardings(mesh, batch_abstract)
 
-    def prefill_fn(params, batch, cache):
+    # the names give the compiled modules theirs: jit_serve_prefill and
+    # jit_serve_decode, which a profiler trace of the step shows
+    def serve_prefill(params, batch, cache):
         return lm.prefill(cfg, params, batch, cache)
 
-    def decode_fn(params, tok, cache):
+    def serve_decode(params, tok, cache):
         return lm.decode_step(cfg, params, tok, cache)
 
     tok_abstract = jax.ShapeDtypeStruct(
@@ -100,13 +102,13 @@ def make_serve_steps(cfg: ModelConfig, mesh: Mesh, specs, cache_abstract,
     tok_sh = batch_shardings(mesh, {"tok": tok_abstract})["tok"]
 
     prefill_step = jax.jit(
-        prefill_fn,
+        serve_prefill,
         in_shardings=(param_sh, batch_sh, cache_sh),
         out_shardings=(None, cache_sh),
         donate_argnums=(2,),
     )
     decode_step = jax.jit(
-        decode_fn,
+        serve_decode,
         in_shardings=(param_sh, tok_sh, cache_sh),
         out_shardings=(None, cache_sh),
         donate_argnums=(2,),

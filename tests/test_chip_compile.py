@@ -6,6 +6,7 @@ see: blocks that exceed scoped VMEM, slices off the tiling, programs that
 do not fit the device.  The topology is described inside the fixture, so
 that importing this file loads no TPU library.
 """
+import re
 from functools import partial
 
 import jax
@@ -17,6 +18,7 @@ from repro.configs import get_config
 from repro.core.autotile import tcm_matmul_tiles
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.matmul import matmul_pallas
+from repro.obs.serving import LAYERS, SCOPES
 
 # qwen1.5-0.5b projections at 8x1024 prefill tokens and 8 decode tokens:
 # q/k/v/o, gate/up, down, lm_head
@@ -73,8 +75,10 @@ def test_flash_attention_qwen_heads_compile(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_qwen_decode_step_compiles_on_one_chip(one_chip):
-    """The served decode step at published widths fits one v5e."""
+@pytest.fixture(scope="module")
+def qwen_decode(one_chip):
+    """The served decode step of qwen1.5-0.5b at published widths,
+    compiled once for one described v5e."""
     from repro.launch.mesh import make_elastic_mesh
     from repro.models import lm
     from repro.serving.engine import make_serve_steps
@@ -94,9 +98,24 @@ def test_qwen_decode_step_compiles_on_one_chip(one_chip):
             lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
             tree, shardings)
 
-    compiled = decode.lower(
+    return decode.lower(
         placed(params_abs, param_sh),
         jax.ShapeDtypeStruct((B, 1), jnp.int32, sharding=tok_sh),
         placed(cache_abs, cache_sh)).compile()
-    mem = compiled.memory_analysis()
+
+
+def test_qwen_decode_step_compiles_on_one_chip(qwen_decode):
+    """The served decode step at published widths fits one v5e."""
+    mem = qwen_decode.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+
+
+def test_qwen_decode_step_keeps_every_scope(qwen_decode):
+    """Each layer kind's named scope survives into the compiled v5e
+    program's op_name metadata, fused computations included, so a device
+    trace of the step can be read by scope."""
+    text = qwen_decode.as_text()
+    paths = set(re.findall(r'op_name="([^"]*)"', text))
+    scopes = {part for path in paths for part in path.split("/")}
+    assert set(SCOPES) | {LAYERS} <= scopes
+    assert text.startswith("HloModule jit_serve_decode,")

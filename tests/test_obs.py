@@ -348,3 +348,139 @@ def test_gap_trace_baseline_spans():
     assert spans[0]["args"]["final_gap"] >= 1.0
     # the exact optimum's search telemetry rides along
     assert any(e["name"] == "tcm_map:mm" for e in tr.events)
+
+
+# --------------------------------------------------------------------------
+# the served step: garbage-collection watch and device scopes
+# --------------------------------------------------------------------------
+
+
+def test_gc_watch_counts_a_forced_collection():
+    import gc
+
+    from repro.obs.serving import GcWatch
+
+    watch = GcWatch().install()
+    try:
+        before = watch.snapshot()
+        gc.collect()  # a full collection, with the profiler off
+        got = watch.snapshot() - before
+    finally:
+        watch.uninstall()
+    assert got.collections[2] >= 1
+    assert got.pause_s > 0.0
+
+
+def test_gc_watch_installs_once_and_uninstalls():
+    import gc
+
+    from repro.obs.serving import GcWatch
+
+    watch = GcWatch()
+    n = len(gc.callbacks)
+    assert watch.install().install() is watch
+    assert len(gc.callbacks) == n + 1
+    watch.uninstall()
+    assert len(gc.callbacks) == n
+    before = watch.snapshot()
+    gc.collect()
+    assert watch.snapshot() == before
+
+
+def _gc_spans_and_counts(tmp_path):
+    """The ``gc`` spans on a profile of one forced collection, and what the
+    process's watch counted meanwhile.  The watch is the one the serving
+    process installs; it is left installed if it was."""
+    import gc
+    from pathlib import Path
+
+    import jax
+
+    from repro.obs.serving import GC_SPAN, WATCH
+
+    installed = WATCH._callback in gc.callbacks
+    WATCH.install()
+    try:
+        before = WATCH.snapshot()
+        with jax.profiler.trace(str(tmp_path)):
+            gc.collect()
+        got = WATCH.snapshot() - before
+    finally:
+        if not installed:
+            WATCH.uninstall()
+    profile = jax.profiler.ProfileData.from_file(
+        str(next(Path(tmp_path).rglob("*.xplane.pb"))))
+    spans = [ev for plane in profile.planes
+             if plane.name.startswith("/host:") for line in plane.lines
+             for ev in line.events if ev.name == GC_SPAN]
+    return spans, got
+
+
+def test_gc_watch_puts_each_collection_on_the_trace(tmp_path):
+    spans, got = _gc_spans_and_counts(tmp_path)
+    # the forced collection at least; none the watch did not count
+    assert 1 <= len(spans) <= sum(got.collections)
+
+
+def test_serve_main_installs_the_one_watch(tmp_path, capsys):
+    import gc
+
+    from repro.launch import serve as serve_mod
+    from repro.obs.serving import WATCH, GcWatch
+
+    serve_mod.main(["--arch", "qwen1.5-0.5b", "--smoke", "--batch", "1",
+                    "--prompt-len", "8", "--gen", "2"])
+    assert "gc: " in capsys.readouterr().out
+    watches = [cb for cb in gc.callbacks
+               if isinstance(getattr(cb, "__self__", None), GcWatch)]
+    assert watches == [WATCH._callback]
+    # so a profile after it holds one span per collection, not two
+    spans, got = _gc_spans_and_counts(tmp_path)
+    assert 1 <= len(spans) <= sum(got.collections)
+
+
+@pytest.fixture(scope="module")
+def served_programs():
+    """The compiled text of the smoke-width prefill and decode steps."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.configs import get_config
+    from repro.launch.mesh import make_elastic_mesh
+    from repro.models import lm
+    from repro.serving.engine import make_serve_steps
+    from repro.training.step import _abstract_init
+
+    cfg = get_config("phi3-mini-3.8b", smoke=True)
+    B, P, slots = 2, 16, 32
+    mesh = make_elastic_mesh(target_model=1, devices=jax.devices()[:1])
+    params_abs, specs = _abstract_init(cfg, jax.random.PRNGKey(0))
+    cache_abs = jax.eval_shape(lambda: lm.init_cache(cfg, B, slots))
+    batch_abs = {"tokens": jax.ShapeDtypeStruct((B, P), jnp.int32)}
+    prefill, decode, (param_sh, batch_sh, cache_sh, tok_sh) = \
+        make_serve_steps(cfg, mesh, specs, cache_abs, batch_abs)
+
+    def placed(tree, shardings):
+        return jax.tree.map(
+            lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+            tree, shardings)
+
+    params, cache = placed(params_abs, param_sh), placed(cache_abs, cache_sh)
+    tok = jax.ShapeDtypeStruct((B, 1), jnp.int32, sharding=tok_sh)
+    return {name: step.lower(params, x, cache).compile().as_text()
+            for name, step, x in (
+                ("prefill", prefill, placed(batch_abs, batch_sh)),
+                ("decode", decode, tok))}
+
+
+@pytest.mark.parametrize("step", ["prefill", "decode"])
+def test_served_step_keeps_every_scope(served_programs, step):
+    import re
+
+    from repro.obs.serving import LAYERS, SCOPES
+
+    text = served_programs[step]
+    assert text.startswith(f"HloModule jit_serve_{step},")
+    paths = set(re.findall(r'op_name="([^"]*)"', text))
+    scopes = {part for path in paths for part in path.split("/")}
+    assert set(SCOPES) | {LAYERS} <= scopes
