@@ -320,13 +320,16 @@ def _qkv(cfg, p, x, src):
 
 
 def attention_block(cfg, p: Params, x, positions, *, cache=None,
-                    causal=True, window=0, kv_from=None):
+                    layer=None, causal=True, window=0, kv_from=None):
     """Full attention block; returns (out, new_cache).
 
-    cache layouts (decode):
-      full:  dict(k=(B,Smax,Hkv,Dh), v=..., idx=int32[]) — global attention.
-      ring:  same arrays with Smax == window — local attention keeps only the
-             last ``window`` tokens; keys are stored *already roped* at their
+    cache: a group's stacked cache, dict(k=(L,B,Smax,Hkv,Dh), v=...,
+    idx=int32[L]); the block is layer ``layer`` of it.  Only the new roped
+    K/V rows are written into the stack, and attention reads its layer's
+    K/V back from it.  Layouts (decode):
+      full:  Smax slots of global attention, rows written at ``idx``.
+      ring:  Smax == window — local attention keeps only the last
+             ``window`` tokens; keys are stored *already roped* at their
              absolute positions, slot = pos % window.
     kv_from: cross-attention memory (B, Sm, d) — non-causal, no cache.
     """
@@ -344,46 +347,41 @@ def attention_block(cfg, p: Params, x, positions, *, cache=None,
         k = rope(k, positions, cfg.rope_theta)
         out = flash_attention(q, k, v, causal=causal, window=window)
     else:
-        idx = cache["idx"]
-        Smax = cache["k"].shape[1]
+        idx = lax.dynamic_index_in_dim(cache["idx"], layer, keepdims=False)
+        Smax = cache["k"].shape[2]
         ring = window and Smax == window
         qpos = idx + jnp.arange(S)[None, :].repeat(B, 0)
         q = rope(q, qpos, cfg.rope_theta)
         k = rope(k, qpos, cfg.rope_theta)
-        if ring:
-            if S == 1:
-                slot = idx % window
-                with jax.named_scope(KV_WRITE):
-                    ck = lax.dynamic_update_slice(cache["k"], k.astype(dt),
-                                                  (0, slot, 0, 0))
-                    cv = lax.dynamic_update_slice(cache["v"], v.astype(dt),
-                                                  (0, slot, 0, 0))
-                filled = jnp.minimum(idx + 1, window)
-                out = flash_attention(q, ck, cv, causal=False,
-                                      kv_valid=filled)
-            else:
-                # windowed prefill: compute without the cache, then stash the
-                # last `window` roped K/V at their ring slots
-                assert S >= window, "prefill shorter than window"
-                out = flash_attention(q, k, v, causal=True, window=window,
-                                      q_offset=0)
-                last = jnp.arange(S - window, S)
-                slots = last % window
-                with jax.named_scope(KV_WRITE):
-                    ck = jnp.zeros_like(cache["k"]).at[:, slots].set(
-                        k[:, last].astype(dt))
-                    cv = jnp.zeros_like(cache["v"]).at[:, slots].set(
-                        v[:, last].astype(dt))
-            new_cache = {"k": ck, "v": cv, "idx": idx + S}
+        prefill_ring = ring and S > 1
+        if prefill_ring:
+            # windowed prefill: compute without the cache, then stash the
+            # last `window` roped K/V at their ring slots, pos % window
+            assert S >= window, "prefill shorter than window"
+            out = flash_attention(q, k, v, causal=True, window=window,
+                                  q_offset=0)
+            shift = (S - window) % window
+            k = jnp.roll(k[:, S - window:], shift, axis=1)
+            v = jnp.roll(v[:, S - window:], shift, axis=1)
+            row = 0
         else:
-            with jax.named_scope(KV_WRITE):
-                ck = lax.dynamic_update_slice(cache["k"], k.astype(dt),
-                                              (0, idx, 0, 0))
-                cv = lax.dynamic_update_slice(cache["v"], v.astype(dt),
-                                              (0, idx, 0, 0))
-            new_cache = {"k": ck, "v": cv, "idx": idx + S}
-            out = flash_attention(q, ck, cv, causal=True, window=window,
-                                  q_offset=idx, kv_valid=idx + S)
+            row = idx % window if ring else idx
+        with jax.named_scope(KV_WRITE):
+            ck = lax.dynamic_update_slice(cache["k"], k[None].astype(dt),
+                                          (layer, 0, row, 0, 0))
+            cv = lax.dynamic_update_slice(cache["v"], v[None].astype(dt),
+                                          (layer, 0, row, 0, 0))
+        new_cache = {"k": ck, "v": cv, "idx": lax.dynamic_update_index_in_dim(
+            cache["idx"], idx + S, layer, 0)}
+        if not prefill_ring:
+            lk = lax.dynamic_index_in_dim(ck, layer, keepdims=False)
+            lv = lax.dynamic_index_in_dim(cv, layer, keepdims=False)
+            if ring:
+                out = flash_attention(q, lk, lv, causal=False,
+                                      kv_valid=jnp.minimum(idx + 1, window))
+            else:
+                out = flash_attention(q, lk, lv, causal=True, window=window,
+                                      q_offset=idx, kv_valid=idx + S)
     out = out.reshape(B, S, cfg.q_dim)
     with jax.named_scope(ATTN_OUT):
         out = out @ p["wo"].astype(dt)

@@ -70,52 +70,60 @@ def _layer_params(cfg: ModelConfig, kind: str, key):
     return p, s
 
 
+def _at(tree, i):
+    """Layer ``i`` of a stacked cache entry."""
+    return jax.tree.map(
+        lambda a: lax.dynamic_index_in_dim(a, i, keepdims=False), tree)
+
+
+def _put(tree, new, i):
+    """``tree`` with layer ``i`` of each stacked leaf replaced by ``new``."""
+    return jax.tree.map(
+        lambda a, n: lax.dynamic_update_index_in_dim(a, n, i, 0), tree, new)
+
+
 def _layer_apply(cfg: ModelConfig, kind: str, p: Params, x, positions,
-                 cache=None, enc_out=None):
-    """One block; returns (x, new_cache, aux)."""
+                 cache=None, layer=None, enc_out=None):
+    """One block; returns (x, new_cache, aux).
+
+    ``cache`` is the kind's stacked cache (leading dim: the group's
+    layers) and the block is its layer ``layer``: the block writes its
+    new K/V rows or its recurrent state into the stack and returns it."""
     aux = jnp.zeros((), jnp.float32)
     # sequence parallelism on the residual stream: the per-layer activation
     # checkpoint (scan carry) shards its sequence dim over 'model'
     x = constrain(x, ("batch", "act_seq", None))
     h = rmsnorm(p["ln1"], x, cfg.norm_eps)
     new_cache = cache
-    if kind in ("attn", "enc", "wattn"):
+    if kind in ("attn", "enc", "wattn", "xattn"):
         win = cfg.window if kind == "wattn" else 0
         a, nc = attention_block(
             cfg, p["attn"], h, positions,
-            cache=None if cache is None else cache["attn"],
+            cache=None if cache is None else cache["attn"], layer=layer,
             causal=(kind != "enc"), window=win)
         if cache is not None:
             new_cache = dict(cache, attn=nc)
         x = x + a
-    elif kind == "xattn":
-        a, nc = attention_block(
-            cfg, p["attn"], h, positions,
-            cache=None if cache is None else cache["attn"], causal=True)
+        if kind == "xattn":
+            hc = rmsnorm(p["ln_cross"], x, cfg.norm_eps)
+            if cache is not None and "xk" in cache:
+                a2 = cross_attention_cached(cfg, p["cross"], hc,
+                                            _at(cache["xk"], layer),
+                                            _at(cache["xv"], layer))
+            else:
+                assert enc_out is not None
+                a2, _ = attention_block(cfg, p["cross"], hc, positions,
+                                        kv_from=enc_out)
+            x = x + a2
+    elif kind in ("ssm", "rglru"):
+        block = ssm_block if kind == "ssm" else rglru_block
+        a, st = block(cfg, p[kind],
+                      h, None if cache is None else _at(cache[kind], layer))
+        if cache is not None:
+            new_cache = dict(cache, **{kind: _put(cache[kind], st, layer)})
         x = x + a
-        hc = rmsnorm(p["ln_cross"], x, cfg.norm_eps)
-        if cache is not None and "xk" in cache:
-            a2 = cross_attention_cached(cfg, p["cross"], hc,
-                                        cache["xk"], cache["xv"])
-        else:
-            assert enc_out is not None
-            a2, _ = attention_block(cfg, p["cross"], hc, positions,
-                                    kv_from=enc_out)
-        x = x + a2
-        if cache is not None:
-            new_cache = dict(cache, attn=nc)
-    elif kind == "ssm":
-        a, st = ssm_block(cfg, p["ssm"], h,
-                          None if cache is None else cache["ssm"])
-        if cache is not None:
-            new_cache = dict(cache, ssm=st)
-        return x + a, new_cache, aux
-    elif kind == "rglru":
-        a, st = rglru_block(cfg, p["rglru"], h,
-                            None if cache is None else cache["rglru"])
-        if cache is not None:
-            new_cache = dict(cache, rglru=st)
-        x = x + a
+        if kind == "ssm":
+            return x, new_cache, aux
     else:
         raise ValueError(kind)
 
@@ -203,18 +211,22 @@ def init(cfg: ModelConfig, key) -> Tuple[Params, Params]:
 
 def _apply_group(cfg, kinds, count, group_params, x, positions,
                  caches=None, enc_out=None):
+    """Run a group's stacked layers.  The group's stacked caches ride in
+    the carry, so each layer writes only its new rows into them and XLA
+    updates them in place; a scan over (params, layer index) alone."""
     def body(carry, per_layer):
-        x, aux = carry
-        layer_params, layer_cache = per_layer
+        x, aux, caches = carry
+        layer_params, i = per_layer
         new_caches = []
         for ki, kind in enumerate(kinds):
-            c = None if layer_cache is None else layer_cache[ki]
+            c = None if caches is None else caches[ki]
             x, nc, a = _layer_apply(cfg, kind, layer_params[ki], x,
-                                    positions, cache=c, enc_out=enc_out)
+                                    positions, cache=c, layer=i,
+                                    enc_out=enc_out)
             new_caches.append(nc)
             aux = aux + a
-        out_cache = tuple(new_caches) if layer_cache is not None else None
-        return (x, aux), out_cache
+        out_caches = tuple(new_caches) if caches is not None else None
+        return (x, aux, out_caches), None
 
     if cfg.remat:
         body = jax.checkpoint(
@@ -222,31 +234,16 @@ def _apply_group(cfg, kinds, count, group_params, x, positions,
 
     # the stack of layers, however it is run
     with jax.named_scope(LAYERS):
-        aux0 = jnp.zeros((), jnp.float32)
-        if count == 1:
-            lp = jax.tree.map(lambda a: a[0], group_params)
-            lc = (None if caches is None
-                  else jax.tree.map(lambda a: a[0], caches))
-            (x, aux), nc = body((x, aux0), (lp, lc))
-            nc = None if nc is None else jax.tree.map(lambda a: a[None], nc)
-            return x, nc, aux
-
-        if cfg.unroll_layers:
-            aux = aux0
-            ncs = []
+        carry = (x, jnp.zeros((), jnp.float32), caches)
+        if count == 1 or cfg.unroll_layers:
             for i in range(count):
                 lp = jax.tree.map(lambda a: a[i], group_params)
-                lc = (None if caches is None
-                      else jax.tree.map(lambda a: a[i], caches))
-                (x, aux), nc = body((x, aux), (lp, lc))
-                ncs.append(nc)
-            new_caches = (None if caches is None else
-                          jax.tree.map(lambda *xs: jnp.stack(xs), *ncs))
-            return x, new_caches, aux
-
-        (x, aux), new_caches = lax.scan(body, (x, aux0),
-                                        (group_params, caches))
-        return x, new_caches, aux
+                carry, _ = body(carry, (lp, i))
+        else:
+            layers = None if caches is None else jnp.arange(count)
+            carry, _ = lax.scan(body, carry, (group_params, layers))
+        x, aux, caches = carry
+        return x, caches, aux
 
 
 @jax.named_scope(EMBED)

@@ -4,7 +4,10 @@
 params per the logical rules; KV caches batch-sharded over ('pod','data')
 and kv-heads over 'model' when divisible (replicated otherwise — GQA with
 few KV heads keeps one copy per model group, the standard TP serving
-layout).  Decode donates the cache (in-place update round-trip)."""
+layout).  Both steps donate the cache: the layer scan carries the stacked
+cache and writes only each step's new K/V rows into it, so XLA aliases the
+donated input to the output cache and updates it in place, with no copy of
+the whole cache."""
 from __future__ import annotations
 
 from typing import Any, Dict, Optional, Tuple

@@ -6,6 +6,7 @@ see: blocks that exceed scoped VMEM, slices off the tiling, programs that
 do not fit the device.  The topology is described inside the fixture, so
 that importing this file loads no TPU library.
 """
+import math
 import re
 from functools import partial
 
@@ -119,3 +120,84 @@ def test_qwen_decode_step_keeps_every_scope(qwen_decode):
     scopes = {part for path in paths for part in path.split("/")}
     assert set(SCOPES) | {LAYERS} <= scopes
     assert text.startswith("HloModule jit_serve_decode,")
+
+
+# phi3-mini-3.8b as the benchmark serves it: 4 sequences of 2048 slots,
+# prompts of 1536 tokens, bfloat16 weights and cache
+PHI3_B, PHI3_P, PHI3_SLOTS = 4, 1536, 2048
+_COPY = re.compile(r"^\s*(?:ROOT )?%?(\S+) = (.*?) (copy|copy-start)\(",
+                   re.MULTILINE)
+
+
+@pytest.fixture(scope="module")
+def phi3_steps(one_chip):
+    """The served prefill and decode steps of phi3-mini-3.8b at the
+    benchmark's shapes, compiled once for one described v5e."""
+    import dataclasses
+
+    from repro.launch.mesh import make_elastic_mesh
+    from repro.models import lm
+    from repro.serving.engine import make_serve_steps
+    from repro.training.step import _abstract_init
+
+    cfg = dataclasses.replace(get_config("phi3-mini-3.8b"),
+                              param_dtype="bfloat16", dtype="bfloat16")
+    B = PHI3_B
+    mesh = make_elastic_mesh(target_model=1, devices=[one_chip])
+    params_abs, specs = _abstract_init(cfg, jax.random.PRNGKey(0))
+    cache_abs = jax.eval_shape(lambda: lm.init_cache(cfg, B, PHI3_SLOTS))
+    batch_abs = {"tokens": jax.ShapeDtypeStruct((B, PHI3_P), jnp.int32)}
+    prefill, decode, (param_sh, batch_sh, cache_sh, tok_sh) = \
+        make_serve_steps(cfg, mesh, specs, cache_abs, batch_abs)
+
+    def placed(tree, shardings):
+        return jax.tree.map(
+            lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+            tree, shardings)
+
+    params, cache = placed(params_abs, param_sh), placed(cache_abs, cache_sh)
+    tok = jax.ShapeDtypeStruct((B, 1), jnp.int32, sharding=tok_sh)
+    k = cache_abs["groups"][0][0]["attn"]["k"]
+    return k, {
+        "decode": decode.lower(params, tok, cache).compile(),
+        "prefill": prefill.lower(params, placed(batch_abs, batch_sh),
+                                 cache).compile()}
+
+
+def _copies(text):
+    """Each copy instruction's name, shapes and op_name."""
+    for m in _COPY.finditer(text):
+        line = text[m.start():text.index("\n", m.end())]
+        op = re.search(r'op_name="([^"]*)"', line)
+        shapes = [tuple(int(d) for d in s.split(",") if d)
+                  for s in re.findall(r"\[([\d,]*)\]", m.group(2))]
+        yield m.group(1), shapes, op.group(1) if op else ""
+
+
+@pytest.mark.parametrize("step,temp_gb", [("decode", 0.5), ("prefill", 1.0)])
+def test_phi3_step_updates_the_stacked_cache_in_place(phi3_steps, step,
+                                                      temp_gb):
+    """The step writes its new K/V rows into the donated stacked cache:
+    the compiled program copies neither the whole cache nor a layer's
+    slice of it (only ``attend`` relayouts a slice into 512-slot
+    chunks), and its temporaries hold no second cache."""
+    k, programs = phi3_steps
+    compiled = programs[step]
+    _, B, slots, H, Dh = k.shape
+    layer_elems = B * slots * H * Dh
+    chunked = (slots // 512, B, 512, H, Dh)
+    relayouts = 0
+    for name, shapes, op_name in _copies(compiled.as_text()):
+        for shape in shapes:
+            assert shape != k.shape, (name, op_name)
+            if math.prod(shape) != layer_elems:
+                continue
+            # a copy of one layer's K or V is attend's relayout alone
+            assert shape == chunked or "attend" in op_name.split("/"), (
+                name, shape, op_name)
+            relayouts += 1
+    assert relayouts <= 2  # K and V, once a layer
+    mem = compiled.memory_analysis()
+    cache_bytes = 2 * math.prod(k.shape) * k.dtype.itemsize
+    assert mem.temp_size_in_bytes < temp_gb * 1e9
+    assert mem.alias_size_in_bytes >= cache_bytes
