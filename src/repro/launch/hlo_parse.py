@@ -6,12 +6,17 @@ HLO text ourselves:
 
   * FLOPs     — from ``dot`` ops: 2 * prod(output shape) * prod(contracted
                 lhs dims); scaled through the call graph (while bodies
-                multiply by ``known_trip_count`` from backend_config).
+                multiply by ``known_trip_count`` from backend_config, else
+                by the constant their condition's ROOT compares the
+                counter against with ``direction=LT``, as JAX's scans and
+                fori_loops lower: the TPU compiler keeps no trip count).
   * bytes     — HBM-traffic estimate: sum of operand + output buffer sizes
                 at fusion/op boundaries (slicing ops read only the slice).
   * collective_bytes — operand sizes of all-gather / all-reduce /
                 reduce-scatter / all-to-all / collective-permute, scaled by
-                trip counts (the assignment's prescribed method).
+                trip counts (the assignment's prescribed method); an async
+                ``*-start`` counts as its collective, ``*-done`` not again.
+  * collective_count — how many of each kind run, scaled the same way.
 
 All numbers are PER DEVICE (post-SPMD shapes are shard shapes), which is
 the form the dry run reports (``launch/dryrun.py``).
@@ -56,8 +61,11 @@ class Computation:
     flops: float = 0.0
     bytes_accessed: float = 0.0
     collective_bytes: Dict[str, float] = field(default_factory=dict)
+    collective_count: Dict[str, float] = field(default_factory=dict)
     # call sites: (callee_name, multiplier)
     calls: List[Tuple[str, float]] = field(default_factory=list)
+    # a loop condition's bound: the constant its ROOT ``lt`` compares with
+    lt_bound: Optional[float] = None
 
 
 def _parse_instruction_shapes(line: str) -> List[Tuple[str, str]]:
@@ -70,6 +78,7 @@ def parse_hlo(text: str) -> Dict[str, Computation]:
     # symbol table per computation: %name -> bytes / dims
     sym_bytes: Dict[str, float] = {}
     sym_dims: Dict[str, List[int]] = {}
+    sym_const: Dict[str, float] = {}
     entry_name = None
 
     header_re = re.compile(r"^(ENTRY\s+)?%?([\w\.\-]+)\s*\(.*\)\s*->.*{")
@@ -85,6 +94,7 @@ def parse_hlo(text: str) -> Dict[str, Computation]:
                 entry_name = cur.name
             sym_bytes = {}
             sym_dims = {}
+            sym_const = {}
             # parameters from the signature
             for pm in re.finditer(r"%?([\w\.\-]+):\s*(\w+)\[([0-9,]*)\]", raw):
                 _, b = _shape_bytes(pm.group(2), pm.group(3))
@@ -118,6 +128,8 @@ def parse_hlo(text: str) -> Dict[str, Computation]:
         # op kind = first token after the '=' and output shape annotation
         opm = re.search(r"\)?\s*([a-z][a-z0-9\-]*)\(", rest)
         kind = opm.group(1) if opm else ""
+        if kind.endswith("-start") and kind[:-len("-start")] in _COLLECTIVES:
+            kind = kind[:-len("-start")]
 
         # operand references
         args_m = re.search(r"\((.*?)\)(,|$)", rest)
@@ -125,9 +137,18 @@ def parse_hlo(text: str) -> Dict[str, Computation]:
         if args_m:
             operands = re.findall(r"%([\w\.\-]+)", args_m.group(1))
 
+        if kind == "constant" and not out_dims:
+            cm = re.search(r"constant\((-?\d+)\)", rest)
+            if cm:
+                sym_const[name] = float(cm.group(1))
+        elif (kind == "compare" and im.group(1) and "direction=LT" in rest
+              and len(operands) == 2 and operands[1] in sym_const):
+            cur.lt_bound = sym_const[operands[1]]
+
         if kind in _COLLECTIVES:
             b = sum(sym_bytes.get(o, 0.0) for o in operands) or out_bytes
             cur.collective_bytes[kind] = cur.collective_bytes.get(kind, 0.0) + b
+            cur.collective_count[kind] = cur.collective_count.get(kind, 0.0) + 1
             cur.bytes_accessed += b + out_bytes
         elif kind == "dot":
             lhs = operands[0] if operands else None
@@ -169,13 +190,15 @@ def parse_hlo(text: str) -> Dict[str, Computation]:
 
         # call edges
         if kind == "while":
-            trip = 1.0
             tc = re.search(r'known_trip_count[\\"]*:\s*\{[\\"]*n[\\"]*:'
                            r'[\\"]*(\d+)', rest)
-            if tc:
-                trip = float(tc.group(1))
             body = re.search(r"body=%?([\w\.\-]+)", rest)
             cond = re.search(r"condition=%?([\w\.\-]+)", rest)
+            if tc:
+                trip = float(tc.group(1))
+            else:  # callees print before their callers: cond is parsed
+                c = comps.get(cond.group(1)) if cond else None
+                trip = (c.lt_bound if c else None) or 1.0
             if body:
                 cur.calls.append((body.group(1), trip))
             if cond:
@@ -198,31 +221,36 @@ class HloSummary:
     bytes_accessed: float
     collective_bytes: Dict[str, float]
     total_collective_bytes: float
+    collective_count: Dict[str, float]
 
 
 def summarize(text: str) -> HloSummary:
     comps = parse_hlo(text)
     entry = comps["__entry__"]
-    memo: Dict[str, Tuple[float, float, Dict[str, float]]] = {}
+    Totals = Tuple[float, float, Dict[str, float], Dict[str, float]]
+    memo: Dict[str, Totals] = {}
 
-    def total(name: str, depth=0) -> Tuple[float, float, Dict[str, float]]:
+    def total(name: str, depth=0) -> Totals:
         if name in memo:
             return memo[name]
         c = comps.get(name)
         if c is None or depth > 64:
-            return 0.0, 0.0, {}
-        memo[name] = (0.0, 0.0, {})  # cycle guard
+            return 0.0, 0.0, {}, {}
+        memo[name] = (0.0, 0.0, {}, {})  # cycle guard
         f, b = c.flops, c.bytes_accessed
         coll = dict(c.collective_bytes)
+        count = dict(c.collective_count)
         for callee, mult in c.calls:
-            cf, cb, cc = total(callee, depth + 1)
+            cf, cb, cc, cn = total(callee, depth + 1)
             f += mult * cf
             b += mult * cb
-            for k, v in cc.items():
-                coll[k] = coll.get(k, 0.0) + mult * v
-        memo[name] = (f, b, coll)
+            for mine, theirs in ((coll, cc), (count, cn)):
+                for k, v in theirs.items():
+                    mine[k] = mine.get(k, 0.0) + mult * v
+        memo[name] = (f, b, coll, count)
         return memo[name]
 
-    f, b, coll = total(entry.name)
+    f, b, coll, count = total(entry.name)
     return HloSummary(flops=f, bytes_accessed=b, collective_bytes=coll,
-                      total_collective_bytes=sum(coll.values()))
+                      total_collective_bytes=sum(coll.values()),
+                      collective_count=count)

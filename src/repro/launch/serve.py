@@ -11,9 +11,9 @@ from __future__ import annotations
 
 import argparse
 import time
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from functools import partial
-from typing import List
+from typing import Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -23,7 +23,7 @@ from repro.configs import get_config
 from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_elastic_mesh
 from repro.models import lm
-from repro.obs.serving import WATCH
+from repro.obs.serving import WATCH, collectives
 from repro.serving.engine import make_serve_steps
 from repro.training.step import init_sharded
 
@@ -72,6 +72,10 @@ class ServeRun:
     compile_s: float  # lower + compile of the prefill and decode steps
     prefill_s: float
     decode_s_per_step: float  # nan when G == 1
+    # on a mesh of several devices: per compiled step, {kind: (count,
+    # bytes per device)} of the collectives one run of it issues
+    collectives: Dict[str, Dict[str, Tuple[int, int]]] = field(
+        default_factory=dict)
 
 
 def generate(cfg, mesh, params, specs, batch, gen: int, mode: str = "tp",
@@ -93,6 +97,11 @@ def generate(cfg, mesh, params, specs, batch, gen: int, mode: str = "tp",
     if gen > 1:
         decode_step = decode_step.lower(params, tok_abs, cache).compile()
     compile_s = time.perf_counter() - t0
+    counted = {}
+    if mesh.devices.size > 1:
+        counted["prefill"] = collectives(prefill_step.as_text())
+        if gen > 1:
+            counted["decode"] = collectives(decode_step.as_text())
 
     t0 = time.perf_counter()
     last, cache = prefill_step(params, batch, cache)
@@ -112,7 +121,8 @@ def generate(cfg, mesh, params, specs, batch, gen: int, mode: str = "tp",
     return ServeRun(
         batch=batch, tokens=jnp.concatenate(out_tokens, axis=1), logits=out_logits,
         compile_s=compile_s, prefill_s=prefill_s,
-        decode_s_per_step=t_decode / (gen - 1) if gen > 1 else float("nan"))
+        decode_s_per_step=t_decode / (gen - 1) if gen > 1 else float("nan"),
+        collectives=counted)
 
 
 def main(argv=None, devices=None):
@@ -170,6 +180,10 @@ def main(argv=None, devices=None):
           f"{run.decode_s_per_step*1e3:.2f}ms/step "
           f"({B/max(run.decode_s_per_step, 1e-9):.1f} tok/s)")
     print("sample:", np.asarray(run.tokens[0][:16]))
+    for name, kinds in run.collectives.items():
+        print(f"collectives per {name} step: " + (", ".join(
+            f"{kind} {n} x, {b / 1e6:.3f} MB per device"
+            for kind, (n, b) in kinds.items()) or "none"))
     print(f"gc: {'/'.join(map(str, gc_run.collections))} collections "
           f"(generations 0/1/2), {gc_run.pause_s * 1e3:.3f} ms paused, "
           "compile included")

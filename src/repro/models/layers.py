@@ -100,17 +100,24 @@ def _mask_for(cfgt, q_pos, k_pos, kv_valid):
     return mask  # (qc, kc)
 
 
+def _kv_chunks(x, kv_chunk):
+    """(B, Hkv, Sk, Dh) -> (Sk // kv_chunk, B, Hkv, kv_chunk, Dh): each
+    chunk keeps the (slots, d_head) tiles of its heads."""
+    B, Hkv, Sk, Dh = x.shape
+    return jnp.moveaxis(x.reshape(B, Hkv, Sk // kv_chunk, kv_chunk, Dh), 2, 0)
+
+
 def _flash_fwd_impl(cfgt, q, k, v, q_off_f, kv_valid_f):
     causal, window, q_chunk, kv_chunk, Sk0 = cfgt
     B, Sq, Hkv, rep, Dh = q.shape
-    _, Skp, _, _ = k.shape
+    Skp = k.shape[2]
     nk = Skp // kv_chunk
     nq = Sq // q_chunk
     scale = 1.0 / math.sqrt(Dh)
     q_off = q_off_f.astype(jnp.int32)
     kv_valid = kv_valid_f.astype(jnp.int32)
-    kcs = jnp.moveaxis(k.reshape(B, nk, kv_chunk, Hkv, Dh), 1, 0)
-    vcs = jnp.moveaxis(v.reshape(B, nk, kv_chunk, Hkv, Dh), 1, 0)
+    kcs = _kv_chunks(k, kv_chunk)
+    vcs = _kv_chunks(v, kv_chunk)
     qcs = jnp.moveaxis(q.reshape(B, nq, q_chunk, Hkv, rep, Dh), 1, 0)
     # context parallelism must survive the chunking reshape: shard the
     # *within-chunk* query dim over 'model' — otherwise SPMD runs all nq
@@ -127,7 +134,7 @@ def _flash_fwd_impl(cfgt, q, k, v, q_off_f, kv_valid_f):
             m, l, acc = carry
             kblk, vblk, ci = inputs
             k_pos = ci * kv_chunk + jnp.arange(kv_chunk)
-            s = jnp.einsum("bqgrd,bkgd->bgrqk", qb, kblk,
+            s = jnp.einsum("bqgrd,bgkd->bgrqk", qb, kblk,
                            preferred_element_type=jnp.float32)
             mask = _mask_for(cfgt, q_pos, k_pos, kv_valid)
             s = jnp.where(mask[None, None, None], s, -1e30)
@@ -136,7 +143,7 @@ def _flash_fwd_impl(cfgt, q, k, v, q_off_f, kv_valid_f):
             corr = jnp.exp(m - m_new)
             l_new = l * corr + p.sum(axis=-1)
             acc_new = (acc * corr[..., None]
-                       + jnp.einsum("bgrqk,bkgd->bgrqd",
+                       + jnp.einsum("bgrqk,bgkd->bgrqd",
                                     p.astype(q.dtype), vblk,
                                     preferred_element_type=jnp.float32))
             return (m_new, l_new, acc_new), None
@@ -165,15 +172,15 @@ def _flash_bwd_impl(cfgt, res, dout):
     causal, window, q_chunk, kv_chunk, Sk0 = cfgt
     q, k, v, out, lse, q_off_f, kv_valid_f = res
     B, Sq, Hkv, rep, Dh = q.shape
-    _, Skp, _, _ = k.shape
+    Skp = k.shape[2]
     nk = Skp // kv_chunk
     nq = Sq // q_chunk
     scale = 1.0 / math.sqrt(Dh)
     q_off = q_off_f.astype(jnp.int32)
     kv_valid = kv_valid_f.astype(jnp.int32)
 
-    kcs = jnp.moveaxis(k.reshape(B, nk, kv_chunk, Hkv, Dh), 1, 0)
-    vcs = jnp.moveaxis(v.reshape(B, nk, kv_chunk, Hkv, Dh), 1, 0)
+    kcs = _kv_chunks(k, kv_chunk)
+    vcs = _kv_chunks(v, kv_chunk)
     qcs = jnp.moveaxis(q.reshape(B, nq, q_chunk, Hkv, rep, Dh), 1, 0)
     qcs = constrain(qcs, (None, "batch", "act_seq", None, None, None))
     docs = jnp.moveaxis(dout.reshape(B, nq, q_chunk, Hkv, rep, Dh), 1, 0)
@@ -196,35 +203,38 @@ def _flash_bwd_impl(cfgt, res, dout):
             dq_c, dk, dv = inner
             kblk, vblk, ci = kin
             k_pos = ci * kv_chunk + jnp.arange(kv_chunk)
-            s = jnp.einsum("bqgrd,bkgd->bgrqk", qb, kblk,
+            s = jnp.einsum("bqgrd,bgkd->bgrqk", qb, kblk,
                            preferred_element_type=jnp.float32)
             mask = _mask_for(cfgt, q_pos, k_pos, kv_valid)
             p = jnp.where(mask[None, None, None],
                           jnp.exp(s - lseblk[..., None]), 0.0)
             pb = p.astype(q.dtype)
             dob = doblk.astype(q.dtype)
-            dv_b = jnp.einsum("bgrqk,bqgrd->bkgd", pb, dob,
+            dv_b = jnp.einsum("bgrqk,bqgrd->bgkd", pb, dob,
                               preferred_element_type=jnp.float32)
-            dp = jnp.einsum("bqgrd,bkgd->bgrqk", dob, vblk,
+            dp = jnp.einsum("bqgrd,bgkd->bgrqk", dob, vblk,
                             preferred_element_type=jnp.float32)
             ds = p * (dp - dltblk[..., None])  # (B,g,r,qc,kc) f32
             dsb = ds.astype(q.dtype)
-            dq_b = jnp.einsum("bgrqk,bkgd->bqgrd", dsb, kblk,
-                              preferred_element_type=jnp.float32)
-            dk_b = jnp.einsum("bgrqk,bqgrd->bkgd", dsb, qblk.astype(q.dtype),
+            # bgrqd, then transposed: XLA's CPU backend runs no bf16 dot
+            # whose output puts q before the batch dim g
+            dq_b = jnp.einsum("bgrqk,bgkd->bgrqd", dsb, kblk,
+                              preferred_element_type=jnp.float32
+                              ).transpose(0, 3, 1, 2, 4)
+            dk_b = jnp.einsum("bgrqk,bqgrd->bgkd", dsb, qblk.astype(q.dtype),
                               preferred_element_type=jnp.float32)
             dq_c = dq_c + dq_b * scale
             start = ci * kv_chunk
             dk = lax.dynamic_update_slice(
                 dk, lax.dynamic_slice(
-                    dk, (0, start, 0, 0),
-                    (B, kv_chunk, Hkv, Dh)) + dk_b * scale,
-                (0, start, 0, 0))
+                    dk, (0, 0, start, 0),
+                    (B, Hkv, kv_chunk, Dh)) + dk_b * scale,
+                (0, 0, start, 0))
             dv = lax.dynamic_update_slice(
                 dv, lax.dynamic_slice(
-                    dv, (0, start, 0, 0),
-                    (B, kv_chunk, Hkv, Dh)) + dv_b,
-                (0, start, 0, 0))
+                    dv, (0, 0, start, 0),
+                    (B, Hkv, kv_chunk, Dh)) + dv_b,
+                (0, 0, start, 0))
             return (dq_c, dk, dv), None
 
         dq0 = jnp.zeros((B, q_chunk, Hkv, rep, Dh), jnp.float32)
@@ -232,8 +242,8 @@ def _flash_bwd_impl(cfgt, res, dout):
             kv_step, (dq0, dk, dv), (kcs, vcs, jnp.arange(nk)))
         return (dk, dv), dq_c
 
-    dk0 = jnp.zeros((B, Skp, Hkv, Dh), jnp.float32)
-    dv0 = jnp.zeros((B, Skp, Hkv, Dh), jnp.float32)
+    dk0 = jnp.zeros((B, Hkv, Skp, Dh), jnp.float32)
+    dv0 = jnp.zeros((B, Hkv, Skp, Dh), jnp.float32)
     (dk, dv), dqs = lax.scan(
         q_step, (dk0, dv0),
         (jnp.arange(nq), qcs, docs, lses, deltas))
@@ -259,17 +269,21 @@ _flash.defvjp(_flash_fwd, _flash_bwd_impl)
 @jax.named_scope(ATTEND)
 def flash_attention(q, k, v, *, causal: bool, window: int = 0,
                     q_offset=0, q_chunk: int = 512, kv_chunk: int = 512,
-                    kv_valid=None):
+                    kv_valid=None, heads_first: bool = False):
     """Streaming softmax attention, chunked over q and kv, with a manual
     flash backward (custom_vjp).
 
-    q: (B, Sq, Hq, Dh); k/v: (B, Sk, Hkv, Dh).  GQA: Hq % Hkv == 0.
+    q: (B, Sq, Hq, Dh); k/v: (B, Sk, Hkv, Dh), or (B, Hkv, Sk, Dh) with
+    ``heads_first``, as the stacked cache holds them; the chunks are read
+    heads first either way.  GQA: Hq % Hkv == 0.
     ``q_offset`` is the absolute position of q[0] relative to k[0] (decode
     with a cache passes the fill index).  Peak live block is
     (B, Hkv, rep, q_chunk, kv_chunk) in f32.  Returns (B, Sq, Hq, Dh).
     """
     B, Sq, Hq, Dh = q.shape
-    _, Sk, Hkv, _ = k.shape
+    if not heads_first:
+        k, v = jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2)
+    _, Hkv, Sk, _ = k.shape
     rep = Hq // Hkv
     kv_chunk = min(kv_chunk, Sk)
     q_chunk = min(q_chunk, Sq)
@@ -277,8 +291,8 @@ def flash_attention(q, k, v, *, causal: bool, window: int = 0,
     nk = (Sk + kv_chunk - 1) // kv_chunk
     pad_k = nk * kv_chunk - Sk
     if pad_k:
-        k = jnp.pad(k, ((0, 0), (0, pad_k), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, pad_k), (0, 0), (0, 0)))
+        k = jnp.pad(k, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
+        v = jnp.pad(v, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
     nq = (Sq + q_chunk - 1) // q_chunk
     pad_q = nq * q_chunk - Sq
     qp = jnp.pad(q, ((0, 0), (0, pad_q), (0, 0), (0, 0))) if pad_q else q
@@ -323,10 +337,12 @@ def attention_block(cfg, p: Params, x, positions, *, cache=None,
                     layer=None, causal=True, window=0, kv_from=None):
     """Full attention block; returns (out, new_cache).
 
-    cache: a group's stacked cache, dict(k=(L,B,Smax,Hkv,Dh), v=...,
+    cache: a group's stacked cache, dict(k=(L,B,Hkv,Smax,Dh), v=...,
     idx=int32[L]); the block is layer ``layer`` of it.  Only the new roped
     K/V rows are written into the stack, and attention reads its layer's
-    K/V back from it.  Layouts (decode):
+    K/V back from it.  Slots sit next to d_head, so that a chip's share
+    of the heads is tiled over (slots, d_head) however few heads it holds.
+    Layouts (decode):
       full:  Smax slots of global attention, rows written at ``idx``.
       ring:  Smax == window — local attention keeps only the last
              ``window`` tokens; keys are stored *already roped* at their
@@ -348,7 +364,7 @@ def attention_block(cfg, p: Params, x, positions, *, cache=None,
         out = flash_attention(q, k, v, causal=causal, window=window)
     else:
         idx = lax.dynamic_index_in_dim(cache["idx"], layer, keepdims=False)
-        Smax = cache["k"].shape[2]
+        Smax = cache["k"].shape[3]
         ring = window and Smax == window
         qpos = idx + jnp.arange(S)[None, :].repeat(B, 0)
         q = rope(q, qpos, cfg.rope_theta)
@@ -367,10 +383,10 @@ def attention_block(cfg, p: Params, x, positions, *, cache=None,
         else:
             row = idx % window if ring else idx
         with jax.named_scope(KV_WRITE):
-            ck = lax.dynamic_update_slice(cache["k"], k[None].astype(dt),
-                                          (layer, 0, row, 0, 0))
-            cv = lax.dynamic_update_slice(cache["v"], v[None].astype(dt),
-                                          (layer, 0, row, 0, 0))
+            ck, cv = (lax.dynamic_update_slice(
+                c, jnp.swapaxes(r, 1, 2)[None].astype(dt),
+                (layer, 0, 0, row, 0)) for c, r in ((cache["k"], k),
+                                                    (cache["v"], v)))
         new_cache = {"k": ck, "v": cv, "idx": lax.dynamic_update_index_in_dim(
             cache["idx"], idx + S, layer, 0)}
         if not prefill_ring:
@@ -378,10 +394,12 @@ def attention_block(cfg, p: Params, x, positions, *, cache=None,
             lv = lax.dynamic_index_in_dim(cv, layer, keepdims=False)
             if ring:
                 out = flash_attention(q, lk, lv, causal=False,
-                                      kv_valid=jnp.minimum(idx + 1, window))
+                                      kv_valid=jnp.minimum(idx + 1, window),
+                                      heads_first=True)
             else:
                 out = flash_attention(q, lk, lv, causal=True, window=window,
-                                      q_offset=idx, kv_valid=idx + S)
+                                      q_offset=idx, kv_valid=idx + S,
+                                      heads_first=True)
     out = out.reshape(B, S, cfg.q_dim)
     with jax.named_scope(ATTN_OUT):
         out = out @ p["wo"].astype(dt)
@@ -389,18 +407,20 @@ def attention_block(cfg, p: Params, x, positions, *, cache=None,
 
 
 def cross_attention_cached(cfg, p: Params, x, ck, cv):
-    """Cross-attention against precomputed (cached) memory K/V."""
+    """Cross-attention against precomputed (cached) memory K/V, each
+    (B, Hkv, Sm, Dh) as ``cross_kv`` gives them."""
     B, S, _ = x.shape
     dt = cfg.jdtype
     q = x @ p["wq"].astype(dt)
     if cfg.qkv_bias:
         q = q + p["bq"].astype(dt)
     q = q.reshape(B, S, cfg.n_heads, cfg.d_head)
-    out = flash_attention(q, ck, cv, causal=False)
+    out = flash_attention(q, ck, cv, causal=False, heads_first=True)
     return out.reshape(B, S, cfg.q_dim) @ p["wo"].astype(dt)
 
 
 def cross_kv(cfg, p: Params, memory):
+    """Memory K/V for the stacked cache, heads before slots."""
     dt = cfg.jdtype
     B, Sm, _ = memory.shape
     k = memory @ p["wk"].astype(dt)
@@ -408,8 +428,8 @@ def cross_kv(cfg, p: Params, memory):
     if cfg.qkv_bias:
         k = k + p["bk"].astype(dt)
         v = v + p["bv"].astype(dt)
-    return (k.reshape(B, Sm, cfg.n_kv_heads, cfg.d_head),
-            v.reshape(B, Sm, cfg.n_kv_heads, cfg.d_head))
+    return tuple(jnp.swapaxes(a.reshape(B, Sm, cfg.n_kv_heads, cfg.d_head),
+                              1, 2) for a in (k, v))
 
 
 # ---------------------------------------------------------------------------

@@ -347,21 +347,20 @@ def _layer_cache(cfg: ModelConfig, kind: str, batch: int, max_len: int):
     from .rglru import init_rglru_state
     from .ssm import init_ssm_state
     dt = cfg.jdtype
+
+    def kv(slots):  # heads before slots: see layers.attention_block
+        return jnp.zeros((batch, cfg.n_kv_heads, slots, cfg.d_head), dt)
+
     if kind in ("attn", "xattn"):
-        c = {"attn": {
-            "k": jnp.zeros((batch, max_len, cfg.n_kv_heads, cfg.d_head), dt),
-            "v": jnp.zeros((batch, max_len, cfg.n_kv_heads, cfg.d_head), dt),
-            "idx": jnp.zeros((), jnp.int32)}}
+        c = {"attn": {"k": kv(max_len), "v": kv(max_len),
+                      "idx": jnp.zeros((), jnp.int32)}}
         if kind == "xattn":
-            c["xk"] = jnp.zeros((batch, max_len, cfg.n_kv_heads, cfg.d_head), dt)
-            c["xv"] = jnp.zeros((batch, max_len, cfg.n_kv_heads, cfg.d_head), dt)
+            c["xk"], c["xv"] = kv(max_len), kv(max_len)
         return c
     if kind == "wattn":
         w = min(cfg.window or max_len, max_len)
-        return {"attn": {
-            "k": jnp.zeros((batch, w, cfg.n_kv_heads, cfg.d_head), dt),
-            "v": jnp.zeros((batch, w, cfg.n_kv_heads, cfg.d_head), dt),
-            "idx": jnp.zeros((), jnp.int32)}}
+        return {"attn": {"k": kv(w), "v": kv(w),
+                         "idx": jnp.zeros((), jnp.int32)}}
     if kind == "ssm":
         return {"ssm": init_ssm_state(cfg, batch)}
     if kind == "rglru":

@@ -24,6 +24,10 @@ told apart from ops outside it.
 puts each collection on the profiler's host clock as a ``gc`` span, beside
 the spans of whoever drives the served step.
 
+:func:`collectives` counts the collectives one run of a compiled step
+issues, by kind, from the program's text: on a mesh of several chips,
+the all-reduces of tensor parallelism and anything that moves the cache.
+
 Nothing here imports JAX at import time.
 """
 from __future__ import annotations
@@ -31,7 +35,7 @@ from __future__ import annotations
 import gc
 import time
 from dataclasses import dataclass
-from typing import Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 SCOPES = EMBED, NORM, QKV, KV_WRITE, ATTEND, ATTN_OUT, MLP, LM_HEAD = (
     "embed", "norm", "qkv", "kv_write", "attend", "attn_out", "mlp",
@@ -96,3 +100,16 @@ class GcWatch:
 
 
 WATCH = GcWatch()  # the serving process's watch: install it once, read it
+
+
+def collectives(hlo_text: str) -> Dict[str, Tuple[int, int]]:
+    """``{kind: (count, bytes)}`` of the collectives one run of a compiled
+    step issues, from its optimized HLO text (``compiled.as_text()``),
+    read by ``repro.launch.hlo_parse``: a loop body's collectives count
+    once per trip, and bytes are their operands on one device.  Kinds
+    that do not occur are left out."""
+    from repro.launch.hlo_parse import summarize
+
+    s = summarize(hlo_text)
+    return {k: (round(n), round(s.collective_bytes[k]))
+            for k, n in sorted(s.collective_count.items())}

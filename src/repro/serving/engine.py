@@ -2,12 +2,14 @@
 
 ``make_serve_steps`` builds jit'd prefill/decode with explicit shardings:
 params per the logical rules; KV caches batch-sharded over ('pod','data')
-and kv-heads over 'model' when divisible (replicated otherwise — GQA with
-few KV heads keeps one copy per model group, the standard TP serving
-layout).  Both steps donate the cache: the layer scan carries the stacked
+and kv-heads over 'model' when divisible (else the KV sequence dim over
+'model').  Both steps donate the cache: the layer scan carries the stacked
 cache and writes only each step's new K/V rows into it, so XLA aliases the
 donated input to the output cache and updates it in place, with no copy of
-the whole cache."""
+the whole cache.  That holds on several chips too because the stacked K/V
+are (L, B, Hkv, slots, Dh): a chip's share of the KV heads (two of Yi's
+eight on four chips) keeps slots next to d_head, the layout the layer loop
+reads, so the cache enters and leaves the loop as it is stored."""
 from __future__ import annotations
 
 from typing import Any, Dict, Optional, Tuple
@@ -54,18 +56,17 @@ def cache_shardings(cfg: ModelConfig, cache_abstract, mesh: Mesh):
         if _div(shp[bdim], mesh, batch_axes):
             spec[bdim] = batch_axes
         if nd >= 4:
-            # (L, B, S, H, D) or (L, B, H, N, P): try the head-ish dim
-            hdim = 3 if nd == 5 else 2
-            if spec[hdim] is None and _div(shp[hdim], mesh, "model"):
-                spec[hdim] = "model"
-            elif nd == 5 and _div(shp[2], mesh, "model"):
+            # (L, B, H, S, D) or (L, B, H, N, P): the head dim first
+            if _div(shp[2], mesh, "model"):
+                spec[2] = "model"
+            elif nd == 5 and _div(shp[3], mesh, "model"):
                 # GQA with kv_heads < model size: shard the KV sequence dim
                 # over 'model' instead (ring-attention-style cache layout)
-                spec[2] = "model"
-            if nd == 5 and spec[2] is None and shp[1] == 1 \
-                    and _div(shp[2], mesh, batch_axes):
+                spec[3] = "model"
+            if nd == 5 and spec[3] is None and shp[1] == 1 \
+                    and _div(shp[3], mesh, batch_axes):
                 # batch-1 long-context: shard the sequence dim over data
-                spec[2] = batch_axes
+                spec[3] = batch_axes
         return NamedSharding(mesh, P(*spec))
 
     return jax.tree.map(one, cache_abstract)

@@ -6,6 +6,7 @@ see: blocks that exceed scoped VMEM, slices off the tiling, programs that
 do not fit the device.  The topology is described inside the fixture, so
 that importing this file loads no TPU library.
 """
+import dataclasses
 import math
 import re
 from functools import partial
@@ -30,7 +31,8 @@ HBM_BYTES = 16 * 10 ** 9
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
+    """A described v5e:2x2 host: four chips, none attached."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
@@ -41,14 +43,19 @@ def one_chip():
     with pytest.MonkeyPatch.context() as mp:
         mp.setenv("TPU_LOG_DIR", "disabled")
         try:
-            topo = topologies.get_topology_desc(platform="tpu",
+            desc = topologies.get_topology_desc(platform="tpu",
                                                 topology_name="v5e:2x2")
         except Exception as e:  # no TPU compiler in this installation
             jax.config.update("jax_enable_compilation_cache", enabled)
             pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-        yield topo.devices[0]
+        yield desc
     jax.config.update("jax_enable_compilation_cache", enabled)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return topo.devices[0]
 
 
 def _compile(fn, one_chip, *shapes):
@@ -129,10 +136,10 @@ _COPY = re.compile(r"^\s*(?:ROOT )?%?(\S+) = (.*?) (copy|copy-start)\(",
                    re.MULTILINE)
 
 
-@pytest.fixture(scope="module")
-def phi3_steps(one_chip):
-    """The served prefill and decode steps of phi3-mini-3.8b at the
-    benchmark's shapes, compiled once for one described v5e."""
+def _served_steps(cfg, devices, B, P, slots):
+    """The served prefill and decode steps at bfloat16, compiled for the
+    described ``devices`` (tensor-parallel over all of them), and the
+    placed shape of the first stacked K."""
     import dataclasses
 
     from repro.launch.mesh import make_elastic_mesh
@@ -140,13 +147,11 @@ def phi3_steps(one_chip):
     from repro.serving.engine import make_serve_steps
     from repro.training.step import _abstract_init
 
-    cfg = dataclasses.replace(get_config("phi3-mini-3.8b"),
-                              param_dtype="bfloat16", dtype="bfloat16")
-    B = PHI3_B
-    mesh = make_elastic_mesh(target_model=1, devices=[one_chip])
+    cfg = dataclasses.replace(cfg, param_dtype="bfloat16", dtype="bfloat16")
+    mesh = make_elastic_mesh(target_model=len(devices), devices=devices)
     params_abs, specs = _abstract_init(cfg, jax.random.PRNGKey(0))
-    cache_abs = jax.eval_shape(lambda: lm.init_cache(cfg, B, PHI3_SLOTS))
-    batch_abs = {"tokens": jax.ShapeDtypeStruct((B, PHI3_P), jnp.int32)}
+    cache_abs = jax.eval_shape(lambda: lm.init_cache(cfg, B, slots))
+    batch_abs = {"tokens": jax.ShapeDtypeStruct((B, P), jnp.int32)}
     prefill, decode, (param_sh, batch_sh, cache_sh, tok_sh) = \
         make_serve_steps(cfg, mesh, specs, cache_abs, batch_abs)
 
@@ -157,11 +162,18 @@ def phi3_steps(one_chip):
 
     params, cache = placed(params_abs, param_sh), placed(cache_abs, cache_sh)
     tok = jax.ShapeDtypeStruct((B, 1), jnp.int32, sharding=tok_sh)
-    k = cache_abs["groups"][0][0]["attn"]["k"]
-    return k, {
+    return cache["groups"][0][0]["attn"]["k"], {
         "decode": decode.lower(params, tok, cache).compile(),
         "prefill": prefill.lower(params, placed(batch_abs, batch_sh),
                                  cache).compile()}
+
+
+@pytest.fixture(scope="module")
+def phi3_steps(one_chip):
+    """The served prefill and decode steps of phi3-mini-3.8b at the
+    benchmark's shapes, compiled once for one described v5e."""
+    return _served_steps(get_config("phi3-mini-3.8b"), [one_chip], PHI3_B,
+                         PHI3_P, PHI3_SLOTS)
 
 
 def _copies(text):
@@ -183,9 +195,9 @@ def test_phi3_step_updates_the_stacked_cache_in_place(phi3_steps, step,
     chunks), and its temporaries hold no second cache."""
     k, programs = phi3_steps
     compiled = programs[step]
-    _, B, slots, H, Dh = k.shape
+    _, B, H, slots, Dh = k.shape
     layer_elems = B * slots * H * Dh
-    chunked = (slots // 512, B, 512, H, Dh)
+    chunked = (slots // 512, B, H, 512, Dh)
     relayouts = 0
     for name, shapes, op_name in _copies(compiled.as_text()):
         for shape in shapes:
@@ -201,3 +213,60 @@ def test_phi3_step_updates_the_stacked_cache_in_place(phi3_steps, step,
     cache_bytes = 2 * math.prod(k.shape) * k.dtype.itemsize
     assert mem.temp_size_in_bytes < temp_gb * 1e9
     assert mem.alias_size_in_bytes >= cache_bytes
+
+
+# Yi-34B as the four-chip cell serves it: one pipeline stage of 30 layers,
+# 16 sequences of 4096 slots, prompts of 512 tokens, tensor-parallel 4
+YI_B, YI_P, YI_SLOTS, YI_CHIPS = 16, 512, 4096, 4
+_COLLECTIVE = re.compile(
+    r"^\s*(?:ROOT )?%?(\S+) = (.*?) (all-reduce|all-gather|reduce-scatter|"
+    r"collective-permute|all-to-all)(?:-start)?\(", re.MULTILINE)
+
+
+YI = dataclasses.replace(get_config("yi-34b"), n_layers=30, norm_eps=1e-5)
+
+
+@pytest.fixture(scope="module")
+def yi_steps(topo):
+    """The served prefill and decode steps of the Yi-34B cut, compiled
+    once for the four chips of a described v5e:2x2."""
+    return _served_steps(YI, topo.devices[:YI_CHIPS], YI_B, YI_P, YI_SLOTS)
+
+
+@pytest.mark.parametrize("step,temp_gb", [("decode", 0.5), ("prefill", 1.0)])
+def test_yi_tp4_step_keeps_each_chips_cache_in_place(yi_steps, step,
+                                                     temp_gb):
+    """Tensor-parallel over four chips, each chip's share of the stacked
+    cache (its KV heads) is updated in place: no copy of a whole shard
+    into or out of the layer loop, no temporaries that hold one, the
+    donated cache aliased, and the window within a chip's 16 GB."""
+    k, programs = yi_steps
+    shard = k.sharding.shard_shape(k.shape)
+    compiled = programs[step]
+    for name, shapes, op_name in _copies(compiled.as_text()):
+        assert shard not in shapes, (name, op_name)
+    mem = compiled.memory_analysis()
+    cache_bytes = 2 * math.prod(shard) * k.dtype.itemsize
+    assert mem.temp_size_in_bytes < temp_gb * 1e9
+    assert mem.alias_size_in_bytes >= cache_bytes
+    window = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+              + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert window < HBM_BYTES
+
+
+def test_yi_tp4_decode_all_reduces_only_the_residual(yi_steps):
+    """Each layer all-reduces the (B, 1, d_model) residual twice, after
+    attention's output projection and after the MLP, and the embedding
+    lookup once: nothing else crosses chips, the cache least of all."""
+    from repro.obs.serving import collectives
+
+    _, programs = yi_steps
+    text = programs["decode"].as_text()
+    n = 2 * YI.n_layers + 1
+    residual = (YI_B, 1, YI.d_model)
+    assert collectives(text) == {
+        "all-reduce": (n, n * math.prod(residual) * 2)}
+    for m in _COLLECTIVE.finditer(text):
+        shapes = [tuple(int(d) for d in s.split(",") if d)
+                  for s in re.findall(r"\[([\d,]*)\]", m.group(2))]
+        assert shapes == [residual], m.group(0)

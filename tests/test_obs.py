@@ -484,3 +484,95 @@ def test_served_step_keeps_every_scope(served_programs, step):
     paths = set(re.findall(r'op_name="([^"]*)"', text))
     scopes = {part for path in paths for part in path.split("/")}
     assert set(SCOPES) | {LAYERS} <= scopes
+
+
+# a scan's loop as the TPU compiler leaves it (no known_trip_count: the
+# bound is the constant its condition compares with), a loop that keeps
+# its trip count, and an async all-gather outside both
+COLLECTIVES_HLO = """HloModule jit_step, is_scheduled=true
+
+%add (x: f32[], y: f32[]) -> f32[] {
+  %x = f32[] parameter(0)
+  %y = f32[] parameter(1)
+  ROOT %s = f32[] add(%x, %y)
+}
+
+%cond (p: (s32[], f32[8,4])) -> pred[] {
+  %n = s32[] constant(30)
+  %p = (s32[], f32[8,4]) parameter(0)
+  %i = s32[] get-tuple-element(%p), index=0
+  ROOT %lt = pred[] compare(%i, %n), direction=LT
+}
+
+%body (p.1: (s32[], f32[8,4])) -> (s32[], f32[8,4]) {
+  %p.1 = (s32[], f32[8,4]) parameter(0)
+  %i.1 = s32[] get-tuple-element(%p.1), index=0
+  %x.1 = f32[8,4] get-tuple-element(%p.1), index=1
+  %ar.1 = f32[8,4] all-reduce(%x.1), replica_groups={}, to_apply=%add
+  %ar.2 = f32[8,4] all-reduce(%ar.1), replica_groups={}, to_apply=%add
+  ROOT %t.1 = (s32[], f32[8,4]) tuple(%i.1, %ar.2)
+}
+
+%cond.2 (p.2: (s32[], f32[8,4])) -> pred[] {
+  %p.2 = (s32[], f32[8,4]) parameter(0)
+  ROOT %c.2 = pred[] constant(true)
+}
+
+%body.2 (p.3: (s32[], f32[8,4])) -> (s32[], f32[8,4]) {
+  %p.3 = (s32[], f32[8,4]) parameter(0)
+  %i.3 = s32[] get-tuple-element(%p.3), index=0
+  %x.3 = f32[8,4] get-tuple-element(%p.3), index=1
+  %cp.3 = f32[8,4] collective-permute(%x.3), source_target_pairs={{0,1}}
+  ROOT %t.3 = (s32[], f32[8,4]) tuple(%i.3, %cp.3)
+}
+
+ENTRY %main (a: f32[8,4]) -> f32[8,4] {
+  %a = f32[8,4] parameter(0)
+  %z = s32[] constant(0)
+  %init = (s32[], f32[8,4]) tuple(%z, %a)
+  %w = (s32[], f32[8,4]) while(%init), condition=%cond, body=%body
+  %w.2 = (s32[], f32[8,4]) while(%w), condition=%cond.2, body=%body.2, backend_config={"known_trip_count":{"n":"3"}}
+  %r = f32[8,4] get-tuple-element(%w.2), index=1
+  %ag = f32[32,4] all-gather-start(%r), dimensions={0}
+  ROOT %agd = f32[32,4] all-gather-done(%ag)
+}
+"""
+
+
+def test_collectives_count_each_loop_body_per_trip():
+    """``collectives`` counts a loop body's collectives once per trip,
+    whether the trip count is kept or only the condition's bound is, and
+    an async start once (its done not again); bytes are operands."""
+    from repro.obs.serving import collectives
+
+    each = 8 * 4 * 4
+    assert collectives(COLLECTIVES_HLO) == {
+        "all-gather": (1, each),
+        "all-reduce": (60, 60 * each),
+        "collective-permute": (3, 3 * each)}
+
+
+def test_serve_prints_collectives_on_a_mesh_of_four(tmp_path):
+    """On four (CPU) devices ``launch/serve.py`` prints each compiled
+    step's collectives; in a process of its own, since the device count is
+    fixed before JAX starts."""
+    import os
+    import subprocess
+    import sys
+
+    env = {"PATH": os.environ.get("PATH", "/usr/bin:/bin"),
+           "PYTHONPATH": "src", "JAX_PLATFORMS": "cpu",
+           "JAX_COMPILATION_CACHE_DIR": str(tmp_path),
+           "XLA_FLAGS": "--xla_force_host_platform_device_count=4"}
+    r = subprocess.run(
+        [sys.executable, "-m", "repro.launch.serve", "--arch", "qwen1.5-0.5b",
+         "--smoke", "--batch", "2", "--prompt-len", "8", "--gen", "3",
+         "--model-parallel", "4"],
+        capture_output=True, text=True, timeout=300, env=env,
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    assert r.returncode == 0, r.stderr[-1500:]
+    lines = [ln for ln in r.stdout.splitlines()
+             if ln.startswith("collectives per ")]
+    assert [ln.split(" step:")[0] for ln in lines] == [
+        "collectives per prefill", "collectives per decode"]
+    assert all("all-reduce" in ln for ln in lines), lines
