@@ -66,6 +66,9 @@ class Computation:
     calls: List[Tuple[str, float]] = field(default_factory=list)
     # a loop condition's bound: the constant its ROOT ``lt`` compares with
     lt_bound: Optional[float] = None
+    # (name, op kind, output shapes [(dtype, dims)], rest of the line)
+    instructions: List[Tuple[str, str, List[Tuple[str, str]], str]] = field(
+        default_factory=list)
 
 
 def _parse_instruction_shapes(line: str) -> List[Tuple[str, str]]:
@@ -130,6 +133,8 @@ def parse_hlo(text: str) -> Dict[str, Computation]:
         kind = opm.group(1) if opm else ""
         if kind.endswith("-start") and kind[:-len("-start")] in _COLLECTIVES:
             kind = kind[:-len("-start")]
+        cur.instructions.append((name, kind, _SHAPE_RE.findall(
+            rest[:opm.start(1)] if opm else ""), rest))
 
         # operand references
         args_m = re.search(r"\((.*?)\)(,|$)", rest)
@@ -213,6 +218,19 @@ def parse_hlo(text: str) -> Dict[str, Computation]:
 
     comps["__entry__"] = comps.get(entry_name, Computation("__missing__"))
     return comps
+
+
+def run_counts(comps: Dict[str, Computation]) -> Dict[str, float]:
+    """How often one run of the entry runs each computation it reaches:
+    a loop body once per trip, as :func:`summarize` scales them."""
+    counts = {comps["__entry__"].name: 1.0}
+    # callees print before their callers: walking back from the entry,
+    # every caller of a computation is done before it
+    for name in reversed([n for n in comps if n != "__entry__"]):
+        for callee, mult in comps[name].calls:
+            counts[callee] = counts.get(callee, 0.0) + \
+                counts.get(name, 0.0) * mult
+    return counts
 
 
 @dataclass

@@ -23,7 +23,7 @@ from repro.configs import get_config
 from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_elastic_mesh
 from repro.models import lm
-from repro.obs.serving import WATCH, collectives
+from repro.obs.serving import WATCH, cache_relayouts, collectives
 from repro.serving.engine import make_serve_steps
 from repro.training.step import init_sharded
 
@@ -76,6 +76,10 @@ class ServeRun:
     # bytes per device)} of the collectives one run of it issues
     collectives: Dict[str, Dict[str, Tuple[int, int]]] = field(
         default_factory=dict)
+    # per compiled step, (count, bytes per device) of the buffers one run
+    # of it makes that hold a layer's K or V or a whole stacked K/V leaf
+    cache_relayouts: Dict[str, Tuple[int, int]] = field(
+        default_factory=dict)
 
 
 def generate(cfg, mesh, params, specs, batch, gen: int, mode: str = "tp",
@@ -97,11 +101,12 @@ def generate(cfg, mesh, params, specs, batch, gen: int, mode: str = "tp",
     if gen > 1:
         decode_step = decode_step.lower(params, tok_abs, cache).compile()
     compile_s = time.perf_counter() - t0
-    counted = {}
-    if mesh.devices.size > 1:
-        counted["prefill"] = collectives(prefill_step.as_text())
-        if gen > 1:
-            counted["decode"] = collectives(decode_step.as_text())
+    texts = {"prefill": prefill_step.as_text()}
+    if gen > 1:
+        texts["decode"] = decode_step.as_text()
+    counted = ({name: collectives(text) for name, text in texts.items()}
+               if mesh.devices.size > 1 else {})
+    relaid = {name: cache_relayouts(text) for name, text in texts.items()}
 
     t0 = time.perf_counter()
     last, cache = prefill_step(params, batch, cache)
@@ -122,7 +127,7 @@ def generate(cfg, mesh, params, specs, batch, gen: int, mode: str = "tp",
         batch=batch, tokens=jnp.concatenate(out_tokens, axis=1), logits=out_logits,
         compile_s=compile_s, prefill_s=prefill_s,
         decode_s_per_step=t_decode / (gen - 1) if gen > 1 else float("nan"),
-        collectives=counted)
+        collectives=counted, cache_relayouts=relaid)
 
 
 def main(argv=None, devices=None):
@@ -184,6 +189,9 @@ def main(argv=None, devices=None):
         print(f"collectives per {name} step: " + (", ".join(
             f"{kind} {n} x, {b / 1e6:.3f} MB per device"
             for kind, (n, b) in kinds.items()) or "none"))
+    for name, (n, b) in run.cache_relayouts.items():
+        print(f"cache relayouts per {name} step: {n} x, "
+              f"{b / 1e6:.3f} MB per device")
     print(f"gc: {'/'.join(map(str, gc_run.collections))} collections "
           f"(generations 0/1/2), {gc_run.pause_s * 1e3:.3f} ms paused, "
           "compile included")

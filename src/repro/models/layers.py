@@ -107,17 +107,42 @@ def _kv_chunks(x, kv_chunk):
     return jnp.moveaxis(x.reshape(B, Hkv, Sk // kv_chunk, kv_chunk, Dh), 2, 0)
 
 
-def _flash_fwd_impl(cfgt, q, k, v, q_off_f, kv_valid_f):
+def _stacked_chunk(k, v, layer, kv_chunk, ci):
+    """Chunk ``ci`` of layer ``layer`` of a stacked cache (L, B, Hkv, Sk,
+    Dh), sliced where it lies, and its slots' positions.  A last chunk
+    that would run past Sk starts Sk - kv_chunk in; the rows it shares
+    with the chunk before get position Sk, which ``kv_valid`` masks."""
+    _, B, Hkv, Sk, Dh = k.shape
+    lo = ci * kv_chunk
+    start = jnp.minimum(lo, Sk - kv_chunk)
+    kblk, vblk = (lax.dynamic_slice(c, (layer, 0, 0, start, 0),
+                                    (1, B, Hkv, kv_chunk, Dh))[0]
+                  for c in (k, v))
+    k_pos = start + jnp.arange(kv_chunk)
+    if Sk % kv_chunk:
+        k_pos = jnp.where(k_pos >= lo, k_pos, Sk)
+    return kblk, vblk, k_pos
+
+
+def _flash_fwd_impl(cfgt, q, k, v, q_off_f, kv_valid_f, layer=None):
     causal, window, q_chunk, kv_chunk, Sk0 = cfgt
     B, Sq, Hkv, rep, Dh = q.shape
-    Skp = k.shape[2]
-    nk = Skp // kv_chunk
     nq = Sq // q_chunk
     scale = 1.0 / math.sqrt(Dh)
     q_off = q_off_f.astype(jnp.int32)
     kv_valid = kv_valid_f.astype(jnp.int32)
-    kcs = _kv_chunks(k, kv_chunk)
-    vcs = _kv_chunks(v, kv_chunk)
+    if layer is None:  # k/v (B, Hkv, Skp, Dh), relaid into chunks
+        nk = k.shape[2] // kv_chunk
+        kv_xs = (_kv_chunks(k, kv_chunk), _kv_chunks(v, kv_chunk),
+                 jnp.arange(nk))
+
+        def read(xs):
+            kblk, vblk, ci = xs
+            return kblk, vblk, ci * kv_chunk + jnp.arange(kv_chunk)
+    else:  # a stacked cache, each chunk read from it in the loop
+        nk = -(-k.shape[3] // kv_chunk)
+        kv_xs = jnp.arange(nk)
+        read = partial(_stacked_chunk, k, v, layer, kv_chunk)
     qcs = jnp.moveaxis(q.reshape(B, nq, q_chunk, Hkv, rep, Dh), 1, 0)
     # context parallelism must survive the chunking reshape: shard the
     # *within-chunk* query dim over 'model' — otherwise SPMD runs all nq
@@ -132,8 +157,7 @@ def _flash_fwd_impl(cfgt, q, k, v, q_off_f, kv_valid_f):
 
         def kv_step(carry, inputs):
             m, l, acc = carry
-            kblk, vblk, ci = inputs
-            k_pos = ci * kv_chunk + jnp.arange(kv_chunk)
+            kblk, vblk, k_pos = read(inputs)
             s = jnp.einsum("bqgrd,bgkd->bgrqk", qb, kblk,
                            preferred_element_type=jnp.float32)
             mask = _mask_for(cfgt, q_pos, k_pos, kv_valid)
@@ -151,8 +175,7 @@ def _flash_fwd_impl(cfgt, q, k, v, q_off_f, kv_valid_f):
         m0 = jnp.full((B, Hkv, rep, q_chunk), -jnp.inf, jnp.float32)
         l0 = jnp.zeros((B, Hkv, rep, q_chunk), jnp.float32)
         a0 = jnp.zeros((B, Hkv, rep, q_chunk, Dh), jnp.float32)
-        (m, l, acc), _ = lax.scan(
-            kv_step, (m0, l0, a0), (kcs, vcs, jnp.arange(nk)))
+        (m, l, acc), _ = lax.scan(kv_step, (m0, l0, a0), kv_xs)
         l = jnp.maximum(l, 1e-30)
         out = jnp.einsum("bgrqd->bqgrd",
                          acc / l[..., None]).astype(q.dtype)
@@ -269,28 +292,35 @@ _flash.defvjp(_flash_fwd, _flash_bwd_impl)
 @jax.named_scope(ATTEND)
 def flash_attention(q, k, v, *, causal: bool, window: int = 0,
                     q_offset=0, q_chunk: int = 512, kv_chunk: int = 512,
-                    kv_valid=None, heads_first: bool = False):
+                    kv_valid=None, layer=None):
     """Streaming softmax attention, chunked over q and kv, with a manual
     flash backward (custom_vjp).
 
-    q: (B, Sq, Hq, Dh); k/v: (B, Sk, Hkv, Dh), or (B, Hkv, Sk, Dh) with
-    ``heads_first``, as the stacked cache holds them; the chunks are read
-    heads first either way.  GQA: Hq % Hkv == 0.
+    q: (B, Sq, Hq, Dh); k/v: (B, Sk, Hkv, Dh), or, with ``layer``, a
+    stacked cache (L, B, Hkv, Sk, Dh): the KV loop then reads layer
+    ``layer``'s chunks straight from the stack, with no copy of the
+    layer and no backward.  GQA: Hq % Hkv == 0.
     ``q_offset`` is the absolute position of q[0] relative to k[0] (decode
     with a cache passes the fill index).  Peak live block is
     (B, Hkv, rep, q_chunk, kv_chunk) in f32.  Returns (B, Sq, Hq, Dh).
     """
     B, Sq, Hq, Dh = q.shape
-    if not heads_first:
+    if layer is None:
         k, v = jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2)
-    _, Hkv, Sk, _ = k.shape
+    Hkv, Sk = k.shape[-3:-1]
     rep = Hq // Hkv
     kv_chunk = min(kv_chunk, Sk)
     q_chunk = min(q_chunk, Sq)
+    if layer is not None and q_chunk * rep == 1:
+        # one query row a KV head (decode without GQA): XLA would turn each
+        # chunk's product into a multiply-reduce that relays the chunk to
+        # put d_head minor; a second row, padded and dropped, keeps it a
+        # matrix product, which reads the chunk where it lies
+        q_chunk = 2
 
     nk = (Sk + kv_chunk - 1) // kv_chunk
     pad_k = nk * kv_chunk - Sk
-    if pad_k:
+    if pad_k and layer is None:
         k = jnp.pad(k, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
     nq = (Sq + q_chunk - 1) // q_chunk
@@ -302,7 +332,10 @@ def flash_attention(q, k, v, *, causal: bool, window: int = 0,
     q_off_f = jnp.asarray(q_offset, jnp.float32)
     kv_valid_f = jnp.asarray(Sk if kv_valid is None else kv_valid,
                              jnp.float32)
-    out = _flash(cfgt, qg, k, v, q_off_f, kv_valid_f)
+    if layer is None:
+        out = _flash(cfgt, qg, k, v, q_off_f, kv_valid_f)
+    else:
+        out, _ = _flash_fwd_impl(cfgt, qg, k, v, q_off_f, kv_valid_f, layer)
     return out.reshape(B, nq * q_chunk, Hq, Dh)[:, :Sq].astype(q.dtype)
 
 
@@ -340,8 +373,7 @@ def attention_block(cfg, p: Params, x, positions, *, cache=None,
     cache: a group's stacked cache, dict(k=(L,B,Hkv,Smax,Dh), v=...,
     idx=int32[L]); the block is layer ``layer`` of it.  Only the new roped
     K/V rows are written into the stack, and attention reads its layer's
-    K/V back from it.  Slots sit next to d_head, so that a chip's share
-    of the heads is tiled over (slots, d_head) however few heads it holds.
+    chunks of slots where they lie.
     Layouts (decode):
       full:  Smax slots of global attention, rows written at ``idx``.
       ring:  Smax == window — local attention keeps only the last
@@ -385,37 +417,35 @@ def attention_block(cfg, p: Params, x, positions, *, cache=None,
         with jax.named_scope(KV_WRITE):
             ck, cv = (lax.dynamic_update_slice(
                 c, jnp.swapaxes(r, 1, 2)[None].astype(dt),
-                (layer, 0, 0, row, 0)) for c, r in ((cache["k"], k),
-                                                    (cache["v"], v)))
+                (layer, 0, 0, row, 0))
+                for c, r in ((cache["k"], k), (cache["v"], v)))
         new_cache = {"k": ck, "v": cv, "idx": lax.dynamic_update_index_in_dim(
             cache["idx"], idx + S, layer, 0)}
         if not prefill_ring:
-            lk = lax.dynamic_index_in_dim(ck, layer, keepdims=False)
-            lv = lax.dynamic_index_in_dim(cv, layer, keepdims=False)
             if ring:
-                out = flash_attention(q, lk, lv, causal=False,
+                out = flash_attention(q, ck, cv, causal=False,
                                       kv_valid=jnp.minimum(idx + 1, window),
-                                      heads_first=True)
+                                      layer=layer)
             else:
-                out = flash_attention(q, lk, lv, causal=True, window=window,
+                out = flash_attention(q, ck, cv, causal=True, window=window,
                                       q_offset=idx, kv_valid=idx + S,
-                                      heads_first=True)
+                                      layer=layer)
     out = out.reshape(B, S, cfg.q_dim)
     with jax.named_scope(ATTN_OUT):
         out = out @ p["wo"].astype(dt)
     return out, new_cache
 
 
-def cross_attention_cached(cfg, p: Params, x, ck, cv):
-    """Cross-attention against precomputed (cached) memory K/V, each
-    (B, Hkv, Sm, Dh) as ``cross_kv`` gives them."""
+def cross_attention_cached(cfg, p: Params, x, ck, cv, layer):
+    """Cross-attention against layer ``layer`` of the stacked memory K/V,
+    each (L, B, Hkv, Sm, Dh), as ``cross_kv`` gives them per layer."""
     B, S, _ = x.shape
     dt = cfg.jdtype
     q = x @ p["wq"].astype(dt)
     if cfg.qkv_bias:
         q = q + p["bq"].astype(dt)
     q = q.reshape(B, S, cfg.n_heads, cfg.d_head)
-    out = flash_attention(q, ck, cv, causal=False, heads_first=True)
+    out = flash_attention(q, ck, cv, causal=False, layer=layer)
     return out.reshape(B, S, cfg.q_dim) @ p["wo"].astype(dt)
 
 

@@ -108,8 +108,7 @@ def _layer_apply(cfg: ModelConfig, kind: str, p: Params, x, positions,
             hc = rmsnorm(p["ln_cross"], x, cfg.norm_eps)
             if cache is not None and "xk" in cache:
                 a2 = cross_attention_cached(cfg, p["cross"], hc,
-                                            _at(cache["xk"], layer),
-                                            _at(cache["xv"], layer))
+                                            cache["xk"], cache["xv"], layer)
             else:
                 assert enc_out is not None
                 a2, _ = attention_block(cfg, p["cross"], hc, positions,

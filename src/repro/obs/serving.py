@@ -27,12 +27,17 @@ the spans of whoever drives the served step.
 :func:`collectives` counts the collectives one run of a compiled step
 issues, by kind, from the program's text: on a mesh of several chips,
 the all-reduces of tensor parallelism and anything that moves the cache.
+:func:`cache_relayouts` counts, the same way, the buffers it makes that
+hold a layer's K or V or a whole stacked K/V leaf: what reading the
+cache where it lies avoids.
 
 Nothing here imports JAX at import time.
 """
 from __future__ import annotations
 
 import gc
+import math
+import re
 import time
 from dataclasses import dataclass
 from typing import Dict, Optional, Tuple
@@ -113,3 +118,66 @@ def collectives(hlo_text: str) -> Dict[str, Tuple[int, int]]:
     s = summarize(hlo_text)
     return {k: (round(n), round(s.collective_bytes[k]))
             for k, n in sorted(s.collective_count.items())}
+
+
+# a stacked K/V leaf's op_name among a step's parameters
+_KV_PARAM = re.compile(r"cache\[.*\[\\?'(?:k|v|xk|xv)\\?'\]")
+_CALLEE = re.compile(r"calls=%?([\w\.\-]+)")
+# kinds that make no buffer of their own (``copy-done``: its start has)
+_NO_BUFFER = {"parameter", "tuple", "get-tuple-element", "bitcast", "while",
+              "conditional", "call", "constant", "dynamic-update-slice",
+              "copy-done", "optimization-barrier"}
+
+
+def _dims(dims: str) -> Tuple[int, ...]:
+    return tuple(int(d) for d in dims.split(",") if d)
+
+
+def cache_relayouts(hlo_text: str) -> Tuple[int, int]:
+    """``(count, bytes)`` of the buffers one run of a compiled step makes
+    that hold one layer's K or V, or a whole stacked K/V leaf, from its
+    optimized HLO text (``compiled.as_text()``): copies, fusions, slices,
+    transposes and reshapes of the cache.  Loop bodies count once per
+    trip, as :func:`collectives` counts them, and shapes are one
+    device's.  The stacked K/V are the step's 5-D parameters named
+    ``cache[...]['k']`` (``'v'``, ``'xk'``, ``'xv'``); a buffer is one of
+    theirs when it holds as many elements as a layer of a leaf, or the
+    whole leaf, and has a d_head dim.  Writes into the stack in place
+    (a ``dynamic-update-slice``, or a fusion whose root is one) make no
+    buffer, nor do parameters, tuples and bitcasts."""
+    from repro.launch.hlo_parse import _shape_bytes, parse_hlo, run_counts
+
+    comps = parse_hlo(hlo_text)
+    d_head = {}  # elements of a layer of a leaf, or of the leaf -> d_head
+    for _, kind, shapes, rest in comps["__entry__"].instructions:
+        op = re.search(r'op_name="([^"]*)"', rest)
+        if (kind == "parameter" and op and _KV_PARAM.fullmatch(op.group(1))
+                and shapes and len(_dims(shapes[0][1])) == 5):
+            dims = _dims(shapes[0][1])
+            d_head[math.prod(dims[1:])] = d_head[math.prod(dims)] = dims[-1]
+
+    fused, in_place = set(), set()
+    for c in comps.values():
+        for _, kind, _, rest in c.instructions:
+            callee = _CALLEE.search(rest)
+            if kind == "fusion" and callee:
+                fused.add(callee.group(1))
+        if c.instructions and c.instructions[-1][1] == "dynamic-update-slice":
+            in_place.add(c.name)  # its root, which prints last
+    runs = run_counts(comps)
+    count = nbytes = 0.0
+    for name, c in comps.items():
+        if name == "__entry__" or name in fused:
+            continue
+        for _, kind, shapes, rest in c.instructions:
+            callee = _CALLEE.search(rest)
+            if kind in _NO_BUFFER or (kind == "fusion" and callee
+                                      and callee.group(1) in in_place):
+                continue
+            made = [_shape_bytes(dt, dims)[1] for dt, dims in shapes
+                    if d_head.get(math.prod(_dims(dims))) in _dims(dims)]
+            if kind.endswith("-start"):
+                made = made[:1]  # the operand and its copy: one buffer
+            count += runs.get(name, 0.0) * len(made)
+            nbytes += runs.get(name, 0.0) * sum(made)
+    return round(count), round(nbytes)

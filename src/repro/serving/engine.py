@@ -6,16 +6,17 @@ and kv-heads over 'model' when divisible (else the KV sequence dim over
 'model').  Both steps donate the cache: the layer scan carries the stacked
 cache and writes only each step's new K/V rows into it, so XLA aliases the
 donated input to the output cache and updates it in place, with no copy of
-the whole cache.  That holds on several chips too because the stacked K/V
-are (L, B, Hkv, slots, Dh): a chip's share of the KV heads (two of Yi's
-eight on four chips) keeps slots next to d_head, the layout the layer loop
-reads, so the cache enters and leaves the loop as it is stored."""
+the whole cache.  The stacked K/V (L, B, Hkv, slots, Dh) keep one pinned
+layout (:func:`kv_layout`) from the program that makes them through every
+step, and attention reads each chunk of slots where it lies, so no step
+relays the cache or a layer of it, on one chip or on several."""
 from __future__ import annotations
 
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Format, Layout
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.distributed.sharding import shardings_for
@@ -41,11 +42,30 @@ def _div(x: int, mesh: Mesh, names) -> bool:
     return s > 1 and x % s == 0
 
 
+# the stacked K/V leaves of a cache (``lm._layer_cache``), each
+# (L, B, Hkv, slots, Dh); recurrent states keep the default layout
+KV_LEAVES = ("k", "v", "xk", "xv")
+LANES = 128  # a TPU tiles an array's two minor dims over (8, 128)
+
+
+def kv_layout(d_head: int) -> Layout:
+    """The order a stacked K/V leaf lies in, major to minor: d_head minor
+    where it fills whole lanes, else slots minor, so that no d_head (96)
+    is padded to the lane width.  The steps' products read a chunk of
+    slots in place either way, and each layer loop keeps this layout."""
+    if d_head % LANES == 0:
+        return Layout(major_to_minor=(0, 1, 2, 3, 4))
+    return Layout(major_to_minor=(0, 1, 2, 4, 3))
+
+
 def cache_shardings(cfg: ModelConfig, cache_abstract, mesh: Mesh):
-    """Structural sharding for a cache pytree (built from abstract shapes)."""
+    """Structural sharding for a cache pytree (built from abstract shapes).
+    Each stacked K/V leaf gets a ``Format`` of its sharding and
+    :func:`kv_layout`, so the program that makes the cache and every step
+    that takes and returns it keep one layout."""
     batch_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
 
-    def one(x):
+    def one(path, x):
         shp = x.shape
         nd = len(shp)
         if nd == 0:
@@ -67,9 +87,12 @@ def cache_shardings(cfg: ModelConfig, cache_abstract, mesh: Mesh):
                     and _div(shp[3], mesh, batch_axes):
                 # batch-1 long-context: shard the sequence dim over data
                 spec[3] = batch_axes
-        return NamedSharding(mesh, P(*spec))
+        sharding = NamedSharding(mesh, P(*spec))
+        if nd == 5 and getattr(path[-1], "key", None) in KV_LEAVES:
+            return Format(kv_layout(shp[4]), sharding)
+        return sharding
 
-    return jax.tree.map(one, cache_abstract)
+    return jax.tree_util.tree_map_with_path(one, cache_abstract)
 
 
 def batch_shardings(mesh: Mesh, batch_abstract):

@@ -554,8 +554,8 @@ def test_collectives_count_each_loop_body_per_trip():
 
 def test_serve_prints_collectives_on_a_mesh_of_four(tmp_path):
     """On four (CPU) devices ``launch/serve.py`` prints each compiled
-    step's collectives; in a process of its own, since the device count is
-    fixed before JAX starts."""
+    step's collectives and cache relayouts; in a process of its own, since
+    the device count is fixed before JAX starts."""
     import os
     import subprocess
     import sys
@@ -576,3 +576,144 @@ def test_serve_prints_collectives_on_a_mesh_of_four(tmp_path):
     assert [ln.split(" step:")[0] for ln in lines] == [
         "collectives per prefill", "collectives per decode"]
     assert all("all-reduce" in ln for ln in lines), lines
+    assert [ln.split(" step:")[0] for ln in r.stdout.splitlines()
+            if ln.startswith("cache relayouts per ")] == [
+        "cache relayouts per prefill", "cache relayouts per decode"]
+
+
+# a stacked K leaf (4 layers of (2, 3, 8, 16)) among a step's parameters,
+# a layer loop of 4 trips that slices a layer out (a buffer each trip) and
+# writes its rows back in place, a whole-leaf copy after it, a weight and
+# a flat buffer of a layer's element count that are no K/V
+RELAYOUTS_HLO = r"""HloModule jit_step, is_scheduled=true
+
+%slice_layer (p.0: bf16[4,2,3,8,16], i.0: s32[]) -> bf16[2,3,8,16] {
+  %p.0 = bf16[4,2,3,8,16]{4,3,2,1,0} parameter(0)
+  %i.0 = s32[] parameter(1)
+  %z.0 = s32[] constant(0)
+  %ds.0 = bf16[1,2,3,8,16]{4,3,2,1,0} dynamic-slice(%p.0, %i.0, %z.0, %z.0, %z.0, %z.0), dynamic_slice_sizes={1,2,3,8,16}
+  ROOT %b.0 = bf16[2,3,8,16]{3,2,1,0} bitcast(%ds.0)
+}
+
+%write_row (p.1: bf16[4,2,3,8,16], r.1: bf16[1,2,3,1,16], i.1: s32[]) -> bf16[4,2,3,8,16] {
+  %p.1 = bf16[4,2,3,8,16]{4,3,2,1,0} parameter(0)
+  %r.1 = bf16[1,2,3,1,16]{4,3,2,1,0} parameter(1)
+  %i.1 = s32[] parameter(2)
+  %z.1 = s32[] constant(0)
+  ROOT %dus.1 = bf16[4,2,3,8,16]{4,3,2,1,0} dynamic-update-slice(%p.1, %r.1, %i.1, %z.1, %z.1, %z.1, %z.1)
+}
+
+%cond (c: (s32[], bf16[4,2,3,8,16])) -> pred[] {
+  %c = (s32[], bf16[4,2,3,8,16]) parameter(0)
+  ROOT %t = pred[] constant(true)
+}
+
+%body (s: (s32[], bf16[4,2,3,8,16])) -> (s32[], bf16[4,2,3,8,16]) {
+  %s = (s32[], bf16[4,2,3,8,16]) parameter(0)
+  %i = s32[] get-tuple-element(%s), index=0
+  %st = bf16[4,2,3,8,16]{4,3,2,1,0} get-tuple-element(%s), index=1
+  %layer = bf16[2,3,8,16]{3,2,1,0} fusion(%st, %i), kind=kLoop, calls=%slice_layer
+  %flat = bf16[768]{0} reshape(%layer)
+  %row = bf16[1,2,3,1,16]{4,3,2,1,0} slice(%st), slice={[0:1], [0:2], [0:3], [0:1], [0:16]}
+  %st.1 = bf16[4,2,3,8,16]{4,3,2,1,0} fusion(%st, %row, %i), kind=kLoop, calls=%write_row
+  ROOT %out = (s32[], bf16[4,2,3,8,16]) tuple(%i, %st.1)
+}
+
+ENTRY %main (k: bf16[4,2,3,8,16], w: bf16[2,3,8,16]) -> bf16[4,2,3,8,16] {
+  %k = bf16[4,2,3,8,16]{4,3,2,1,0} parameter(0), metadata={op_name="cache[\'groups\'][0][0][\'attn\'][\'k\']"}
+  %w = bf16[2,3,8,16]{3,2,1,0} parameter(1), metadata={op_name="params[\'wk\']"}
+  %z = s32[] constant(0)
+  %init = (s32[], bf16[4,2,3,8,16]) tuple(%z, %k)
+  %loop = (s32[], bf16[4,2,3,8,16]) while(%init), condition=%cond, body=%body, backend_config={"known_trip_count":{"n":"4"}}
+  %st.2 = bf16[4,2,3,8,16]{4,3,2,1,0} get-tuple-element(%loop), index=1
+  ROOT %whole = bf16[4,2,3,8,16]{3,4,2,1,0} copy(%st.2)
+}
+"""
+
+
+def test_cache_relayouts_counts_each_layer_buffer_per_trip():
+    """``cache_relayouts`` counts the layer sliced out in each of the
+    loop's 4 trips and the whole-leaf copy; not the fused slice inside,
+    the in-place row write, the cache parameter, a weight of a layer's
+    shape, or a flat buffer without a d_head dim."""
+    from repro.obs.serving import cache_relayouts
+
+    layer = 2 * 3 * 8 * 16 * 2
+    assert cache_relayouts(RELAYOUTS_HLO) == (4 + 1, 4 * layer + 4 * layer)
+
+
+def test_served_cache_keeps_its_format_and_logits():
+    """On the CPU, the cache made by ``jax.jit(init_cache, out_shardings=
+    cache_sh)``, as the benchmark makes it, keeps the format that
+    ``make_serve_steps`` gives its K/V through a reset, a prefill and
+    decode steps, and the smoke-width logits match the per-layer-scan
+    reference of ``test_stacked_cache``."""
+    from functools import partial
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.experimental.layout import Format
+    from test_stacked_cache import _per_layer_scan_group
+
+    from repro.configs import get_config
+    from repro.launch.mesh import make_elastic_mesh
+    from repro.models import lm
+    from repro.serving.engine import make_serve_steps
+
+    cfg = get_config("phi3-mini-3.8b", smoke=True)
+    B, P, steps = 2, 12, 3
+    rng = np.random.default_rng(5)
+    tokens = jnp.asarray(rng.integers(0, cfg.vocab, (B, P)), jnp.int32)
+    fed = jnp.asarray(rng.integers(0, cfg.vocab, (B, steps)), jnp.int32)
+    params, specs = lm.init(cfg, jax.random.PRNGKey(5))
+    init_cache = partial(lm.init_cache, cfg, B, P + steps)
+    mesh = make_elastic_mesh(target_model=1, devices=jax.devices()[:1])
+    prefill, decode, (param_sh, batch_sh, cache_sh, tok_sh) = \
+        make_serve_steps(cfg, mesh, specs, jax.eval_shape(init_cache),
+                         {"tokens": jax.ShapeDtypeStruct((B, P), jnp.int32)})
+    kv = [(i, f.layout.major_to_minor)
+          for i, f in enumerate(jax.tree.leaves(cache_sh))
+          if isinstance(f, Format)]
+    assert kv
+
+    def formats(cache):
+        leaves = jax.tree.leaves(cache)
+        return [(i, leaves[i].format.layout.major_to_minor) for i, _ in kv]
+
+    reset = jax.jit(lambda c: jax.tree.map(
+        lambda a: (jnp.zeros_like(a)
+                   if jnp.issubdtype(a.dtype, jnp.integer) else a), c),
+        out_shardings=cache_sh, donate_argnums=0)
+    cache = jax.jit(init_cache, out_shardings=cache_sh)()
+    assert formats(cache) == kv
+    cache = reset(cache)
+    assert formats(cache) == kv
+    params = jax.device_put(params, param_sh)
+    last, cache = prefill(params, jax.device_put({"tokens": tokens},
+                                                 batch_sh), cache)
+    served = [last]
+    for t in range(steps):
+        assert formats(cache) == kv
+        out, cache = decode(params, jax.device_put(fed[:, t:t + 1], tok_sh),
+                            cache)
+        served.append(out)
+    assert formats(cache) == kv
+
+    def reference():
+        c = init_cache()
+        out, c = jax.jit(lambda p, b, c: lm.prefill(cfg, p, b, c))(
+            params, {"tokens": tokens}, c)
+        want = [out]
+        step = jax.jit(lambda p, t, c: lm.decode_step(cfg, p, t, c))
+        for t in range(steps):
+            out, c = step(params, fed[:, t:t + 1], c)
+            want.append(out)
+        return want
+
+    with pytest.MonkeyPatch.context() as m:
+        m.setattr(lm, "_apply_group", _per_layer_scan_group)
+        want = reference()
+    for got, ref in zip(served, want):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   rtol=0, atol=1e-6)
