@@ -32,7 +32,6 @@ from repro.optim.adamw import OptConfig, init_opt_state, opt_state_specs
 from repro.serving.engine import batch_shardings, cache_shardings, make_serve_steps
 from repro.distributed.sharding import (activation_sharding_ctx,
                                          shardings_for)
-from repro.training.step import _abstract_init
 
 VLM_PATCHES = 576
 
@@ -113,7 +112,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool, mode: str = "",
     n_dev = mesh.devices.size
 
     t0 = time.time()
-    params_abs, specs = _abstract_init(cfg, jax.random.PRNGKey(0))
+    params_abs, specs = lm.abstract_init(cfg, jax.random.PRNGKey(0))
     param_sh = shardings_for(specs, mesh, mode, like=params_abs)
     batch_abs = input_specs(cfg, cell)
     result = {"arch": arch, "shape": shape,

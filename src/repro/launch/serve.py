@@ -24,42 +24,7 @@ from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_elastic_mesh
 from repro.models import lm
 from repro.obs.serving import WATCH, cache_relayouts, collectives
-from repro.serving.engine import make_serve_steps
-from repro.training.step import init_sharded
-
-
-def _plan_decode_mappings(cfg, B, P, G, deadline_s):
-    """Query the online mapper for every decode step's exact shape.
-
-    The KV length grows by one per generated token, so the G steps
-    collapse onto a handful of shape buckets — the printed summary shows
-    how many searches the whole trajectory actually paid.  Lazy imports
-    keep the mapper out of the serving path unless asked for.
-    """
-    from repro.core.presets import tpu_v4i_like
-    from repro.serve_map import MappingService
-    from repro.serving.engine import decode_mapping_plan
-
-    arch = tpu_v4i_like()
-    t0 = time.perf_counter()
-    with MappingService() as svc:
-        worst_gap = 1.0
-        for step in range(G):
-            plan = decode_mapping_plan(cfg, svc, arch, B, P + step + 1,
-                                       deadline_s=deadline_s)
-            worst_gap = max(worst_gap,
-                            max(r.gap_bound for r in plan.values()))
-        svc.drain_warm(timeout_s=60.0)
-        st = svc.stats
-        p50, p99 = st.latency_quantiles()
-    t_plan = time.perf_counter() - t0
-    print(f"map-service: {st.requests} shape queries over {G} decode "
-          f"steps -> {st.searches} searches "
-          f"({st.exact_hits} exact + {st.bucket_hits} bucket hits, "
-          f"{st.coalesced} coalesced); "
-          f"p50 {p50 * 1e3:.2f}ms p99 {p99 * 1e3:.2f}ms, "
-          f"worst certified gap {worst_gap:.3f}, "
-          f"planned in {t_plan:.2f}s")
+from repro.serving.engine import init_params, make_serve_steps
 
 
 @dataclass
@@ -144,13 +109,6 @@ def main(argv=None, devices=None):
     ap.add_argument("--gen", type=int, default=32)
     ap.add_argument("--mode", default="tp")
     ap.add_argument("--model-parallel", type=int, default=1)
-    ap.add_argument("--map-service", action="store_true",
-                    help="plan the decode tiling online: query the mapping "
-                    "service (repro.serve_map) at every decode step's exact "
-                    "(batch, kv_len) shape and print the bucket-collapse "
-                    "summary before running")
-    ap.add_argument("--map-deadline-ms", type=float, default=50.0,
-                    help="per-query deadline for --map-service (ms)")
     args = ap.parse_args(argv)
     use_compile_cache()
     WATCH.install()  # counted below; `gc` spans in any profile taken
@@ -159,9 +117,6 @@ def main(argv=None, devices=None):
     mesh = make_elastic_mesh(target_model=args.model_parallel,
                              devices=devices)
     B, P, G = args.batch, args.prompt_len, args.gen
-
-    if args.map_service:
-        _plan_decode_mappings(cfg, B, P, G, args.map_deadline_ms / 1e3)
 
     rng = np.random.default_rng(0)
     batch = {"tokens": jnp.asarray(
@@ -175,7 +130,7 @@ def main(argv=None, devices=None):
         batch["enc_frames"] = jnp.asarray(
             rng.normal(size=(B, P, cfg.frontend_dim)), jnp.float32)
 
-    params, specs, _ = init_sharded(cfg, None, mesh, mode=args.mode)
+    params, specs = init_params(cfg, mesh, mode=args.mode)
     gc_before = WATCH.snapshot()
     run = generate(cfg, mesh, params, specs, batch, G, mode=args.mode,
                    extra_len=extra_len)

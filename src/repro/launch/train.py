@@ -30,7 +30,7 @@ from repro.data.pipeline import DataConfig, SyntheticTokens
 from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_elastic_mesh
 from repro.optim.adamw import OptConfig
-from repro.training.step import init_sharded, make_train_step, _abstract_init
+from repro.training.step import init_sharded, make_train_step
 
 
 def main(argv=None):

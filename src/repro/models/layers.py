@@ -15,7 +15,9 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.distributed.sharding import constrain, constrain_any
-from repro.obs.serving import ATTEND, ATTN_OUT, KV_WRITE, MLP, NORM, QKV
+from repro.obs.serving import ATTEND, ATTN_OUT, MLP, NORM, QKV
+
+from . import kv_cache
 
 Params = Dict
 Specs = Dict
@@ -107,23 +109,6 @@ def _kv_chunks(x, kv_chunk):
     return jnp.moveaxis(x.reshape(B, Hkv, Sk // kv_chunk, kv_chunk, Dh), 2, 0)
 
 
-def _stacked_chunk(k, v, layer, kv_chunk, ci):
-    """Chunk ``ci`` of layer ``layer`` of a stacked cache (L, B, Hkv, Sk,
-    Dh), sliced where it lies, and its slots' positions.  A last chunk
-    that would run past Sk starts Sk - kv_chunk in; the rows it shares
-    with the chunk before get position Sk, which ``kv_valid`` masks."""
-    _, B, Hkv, Sk, Dh = k.shape
-    lo = ci * kv_chunk
-    start = jnp.minimum(lo, Sk - kv_chunk)
-    kblk, vblk = (lax.dynamic_slice(c, (layer, 0, 0, start, 0),
-                                    (1, B, Hkv, kv_chunk, Dh))[0]
-                  for c in (k, v))
-    k_pos = start + jnp.arange(kv_chunk)
-    if Sk % kv_chunk:
-        k_pos = jnp.where(k_pos >= lo, k_pos, Sk)
-    return kblk, vblk, k_pos
-
-
 def _flash_fwd_impl(cfgt, q, k, v, q_off_f, kv_valid_f, layer=None):
     causal, window, q_chunk, kv_chunk, Sk0 = cfgt
     B, Sq, Hkv, rep, Dh = q.shape
@@ -140,14 +125,13 @@ def _flash_fwd_impl(cfgt, q, k, v, q_off_f, kv_valid_f, layer=None):
             kblk, vblk, ci = xs
             return kblk, vblk, ci * kv_chunk + jnp.arange(kv_chunk)
     else:  # a stacked cache, each chunk read from it in the loop
-        nk = -(-k.shape[3] // kv_chunk)
-        kv_xs = jnp.arange(nk)
-        read = partial(_stacked_chunk, k, v, layer, kv_chunk)
+        kv_xs = jnp.arange(-(-Sk0 // kv_chunk))
+        read = partial(kv_cache.chunk, k, v, layer, kv_chunk)
     qcs = jnp.moveaxis(q.reshape(B, nq, q_chunk, Hkv, rep, Dh), 1, 0)
     # context parallelism must survive the chunking reshape: shard the
     # *within-chunk* query dim over 'model' — otherwise SPMD runs all nq
     # chunk iterations redundantly on every model-group device (a measured
-    # 16x compute waste; see EXPERIMENTS.md §Perf cell C)
+    # 16x compute waste)
     qcs = constrain(qcs, (None, "batch", "act_seq", None, None, None))
 
     def q_block(qi_blk):
@@ -296,10 +280,10 @@ def flash_attention(q, k, v, *, causal: bool, window: int = 0,
     """Streaming softmax attention, chunked over q and kv, with a manual
     flash backward (custom_vjp).
 
-    q: (B, Sq, Hq, Dh); k/v: (B, Sk, Hkv, Dh), or, with ``layer``, a
-    stacked cache (L, B, Hkv, Sk, Dh): the KV loop then reads layer
-    ``layer``'s chunks straight from the stack, with no copy of the
-    layer and no backward.  GQA: Hq % Hkv == 0.
+    q: (B, Sq, Hq, Dh); k/v: (B, Sk, Hkv, Dh), or, with ``layer``, the
+    stacked K/V of a ``kv_cache``: the KV loop then reads layer
+    ``layer``'s chunks straight from the stack (``kv_cache.chunk``), with
+    no copy of the layer and no backward.  GQA: Hq % Hkv == 0.
     ``q_offset`` is the absolute position of q[0] relative to k[0] (decode
     with a cache passes the fill index).  Peak live block is
     (B, Hkv, rep, q_chunk, kv_chunk) in f32.  Returns (B, Sq, Hq, Dh).
@@ -370,15 +354,11 @@ def attention_block(cfg, p: Params, x, positions, *, cache=None,
                     layer=None, causal=True, window=0, kv_from=None):
     """Full attention block; returns (out, new_cache).
 
-    cache: a group's stacked cache, dict(k=(L,B,Hkv,Smax,Dh), v=...,
-    idx=int32[L]); the block is layer ``layer`` of it.  Only the new roped
-    K/V rows are written into the stack, and attention reads its layer's
-    chunks of slots where they lie.
-    Layouts (decode):
-      full:  Smax slots of global attention, rows written at ``idx``.
-      ring:  Smax == window — local attention keeps only the last
-             ``window`` tokens; keys are stored *already roped* at their
-             absolute positions, slot = pos % window.
+    cache: a group's stacked ``kv_cache``; the block is layer ``layer`` of
+    it.  Only the new roped K/V rows are written into the stack, and
+    attention reads its layer's chunks of slots where they lie.  Keys are
+    stored *already roped* at their absolute positions, so a ring cache
+    (local attention over the last ``window`` tokens) needs no re-rope.
     kv_from: cross-attention memory (B, Sm, d) — non-causal, no cache.
     """
     B, S, _ = x.shape
@@ -395,40 +375,28 @@ def attention_block(cfg, p: Params, x, positions, *, cache=None,
         k = rope(k, positions, cfg.rope_theta)
         out = flash_attention(q, k, v, causal=causal, window=window)
     else:
-        idx = lax.dynamic_index_in_dim(cache["idx"], layer, keepdims=False)
-        Smax = cache["k"].shape[3]
-        ring = window and Smax == window
+        idx = kv_cache.fill(cache, layer)
         qpos = idx + jnp.arange(S)[None, :].repeat(B, 0)
         q = rope(q, qpos, cfg.rope_theta)
         k = rope(k, qpos, cfg.rope_theta)
+        ring = kv_cache.is_ring(cache, window)
         prefill_ring = ring and S > 1
         if prefill_ring:
-            # windowed prefill: compute without the cache, then stash the
-            # last `window` roped K/V at their ring slots, pos % window
-            assert S >= window, "prefill shorter than window"
+            # windowed prefill: attend without the cache, which keeps only
+            # the last `window` rows
             out = flash_attention(q, k, v, causal=True, window=window,
                                   q_offset=0)
-            shift = (S - window) % window
-            k = jnp.roll(k[:, S - window:], shift, axis=1)
-            v = jnp.roll(v[:, S - window:], shift, axis=1)
-            row = 0
-        else:
-            row = idx % window if ring else idx
-        with jax.named_scope(KV_WRITE):
-            ck, cv = (lax.dynamic_update_slice(
-                c, jnp.swapaxes(r, 1, 2)[None].astype(dt),
-                (layer, 0, 0, row, 0))
-                for c, r in ((cache["k"], k), (cache["v"], v)))
-        new_cache = {"k": ck, "v": cv, "idx": lax.dynamic_update_index_in_dim(
-            cache["idx"], idx + S, layer, 0)}
+        new_cache = kv_cache.write(cache, layer, idx, k, v, window)
         if not prefill_ring:
+            ck, cv = kv_cache.kv(new_cache)
             if ring:
-                out = flash_attention(q, ck, cv, causal=False,
-                                      kv_valid=jnp.minimum(idx + 1, window),
-                                      layer=layer)
+                out = flash_attention(
+                    q, ck, cv, causal=False,
+                    kv_valid=kv_cache.filled(idx, S, window), layer=layer)
             else:
                 out = flash_attention(q, ck, cv, causal=True, window=window,
-                                      q_offset=idx, kv_valid=idx + S,
+                                      q_offset=idx,
+                                      kv_valid=kv_cache.filled(idx, S),
                                       layer=layer)
     out = out.reshape(B, S, cfg.q_dim)
     with jax.named_scope(ATTN_OUT):
@@ -437,8 +405,8 @@ def attention_block(cfg, p: Params, x, positions, *, cache=None,
 
 
 def cross_attention_cached(cfg, p: Params, x, ck, cv, layer):
-    """Cross-attention against layer ``layer`` of the stacked memory K/V,
-    each (L, B, Hkv, Sm, Dh), as ``cross_kv`` gives them per layer."""
+    """Cross-attention against layer ``layer`` of the stacked memory K/V
+    (``kv_cache.memory``), as ``cross_kv`` gives them per layer."""
     B, S, _ = x.shape
     dt = cfg.jdtype
     q = x @ p["wq"].astype(dt)
@@ -450,7 +418,7 @@ def cross_attention_cached(cfg, p: Params, x, ck, cv, layer):
 
 
 def cross_kv(cfg, p: Params, memory):
-    """Memory K/V for the stacked cache, heads before slots."""
+    """A layer's memory K/V, in the order ``kv_cache`` holds them."""
     dt = cfg.jdtype
     B, Sm, _ = memory.shape
     k = memory @ p["wk"].astype(dt)
@@ -458,8 +426,8 @@ def cross_kv(cfg, p: Params, memory):
     if cfg.qkv_bias:
         k = k + p["bk"].astype(dt)
         v = v + p["bv"].astype(dt)
-    return tuple(jnp.swapaxes(a.reshape(B, Sm, cfg.n_kv_heads, cfg.d_head),
-                              1, 2) for a in (k, v))
+    return tuple(kv_cache.heads_first(
+        a.reshape(B, Sm, cfg.n_kv_heads, cfg.d_head)) for a in (k, v))
 
 
 # ---------------------------------------------------------------------------

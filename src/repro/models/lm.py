@@ -1,10 +1,11 @@
 """Model assembly: a uniform functional API over every assigned family.
 
   init(cfg, key)                 -> (params, specs)
+  abstract_init(cfg, key)        -> their shapes and specs, unallocated
   loss_fn(cfg, params, batch)    -> (loss, aux)        (train shapes)
   prefill(cfg, params, batch, cache) -> (last_logits, cache)
   decode_step(cfg, params, tok, cache) -> (logits, cache)
-  init_cache(cfg, batch, max_len) -> cache pytree
+  init_cache(cfg, batch, max_len) -> cache pytree (K/V: ``kv_cache``)
 
 Layer stacks are ``lax.scan``'d over stacked parameters (keeps HLO small so
 the 512-device dry-run compiles fast and collective parsing can scale scan
@@ -30,12 +31,13 @@ from jax import lax
 from repro.distributed.sharding import constrain
 from repro.obs.serving import EMBED, LAYERS, LM_HEAD
 
+from . import kv_cache
 from .config import ModelConfig
 from .layers import (_init, attention_block, attention_params,
                      cross_attention_cached, cross_kv, embedding_params, mlp,
                      mlp_params, moe, moe_params, rmsnorm, rmsnorm_params)
-from .rglru import rglru_block, rglru_params
-from .ssm import ssm_block, ssm_params
+from .rglru import init_rglru_state, rglru_block, rglru_params
+from .ssm import init_ssm_state, ssm_block, ssm_params
 
 Params = Dict[str, Any]
 
@@ -106,9 +108,10 @@ def _layer_apply(cfg: ModelConfig, kind: str, p: Params, x, positions,
         x = x + a
         if kind == "xattn":
             hc = rmsnorm(p["ln_cross"], x, cfg.norm_eps)
-            if cache is not None and "xk" in cache:
-                a2 = cross_attention_cached(cfg, p["cross"], hc,
-                                            cache["xk"], cache["xv"], layer)
+            memory = None if cache is None else kv_cache.memory(cache)
+            if memory is not None:
+                a2 = cross_attention_cached(cfg, p["cross"], hc, *memory,
+                                            layer)
             else:
                 assert enc_out is not None
                 a2, _ = attention_block(cfg, p["cross"], hc, positions,
@@ -206,6 +209,20 @@ def init(cfg: ModelConfig, key) -> Tuple[Params, Params]:
         params["groups"].append(list(per_kind_p))
         specs["groups"].append(list(per_kind_s))
     return params, specs
+
+
+def abstract_init(cfg: ModelConfig, key) -> Tuple[Params, Params]:
+    """:func:`init`'s shapes and specs, with nothing allocated (specs are
+    trace-static)."""
+    specs_holder = {}
+
+    def run(k):
+        p, s = init(cfg, k)
+        specs_holder["specs"] = s
+        return p
+
+    shapes = jax.eval_shape(run, key)
+    return shapes, specs_holder["specs"]
 
 
 def _apply_group(cfg, kinds, count, group_params, x, positions,
@@ -342,28 +359,21 @@ def loss_fn(cfg: ModelConfig, params: Params, batch) -> Tuple[jnp.ndarray, Dict]
 # serving: caches, prefill, decode
 # ---------------------------------------------------------------------------
 
-def _layer_cache(cfg: ModelConfig, kind: str, batch: int, max_len: int):
-    from .rglru import init_rglru_state
-    from .ssm import init_ssm_state
-    dt = cfg.jdtype
-
-    def kv(slots):  # heads before slots: see layers.attention_block
-        return jnp.zeros((batch, cfg.n_kv_heads, slots, cfg.d_head), dt)
-
-    if kind in ("attn", "xattn"):
-        c = {"attn": {"k": kv(max_len), "v": kv(max_len),
-                      "idx": jnp.zeros((), jnp.int32)}}
+def _group_cache(cfg: ModelConfig, kind: str, count: int, batch: int,
+                 max_len: int):
+    """The stacked cache of a group's ``count`` layers of one kind."""
+    if kind in ("attn", "xattn", "wattn"):
+        slots = (min(cfg.window or max_len, max_len) if kind == "wattn"
+                 else max_len)
+        c = {"attn": kv_cache.init(cfg, count, batch, slots)}
         if kind == "xattn":
-            c["xk"], c["xv"] = kv(max_len), kv(max_len)
+            c.update(kv_cache.init_memory(cfg, count, batch, max_len))
         return c
-    if kind == "wattn":
-        w = min(cfg.window or max_len, max_len)
-        return {"attn": {"k": kv(w), "v": kv(w),
-                         "idx": jnp.zeros((), jnp.int32)}}
-    if kind == "ssm":
-        return {"ssm": init_ssm_state(cfg, batch)}
-    if kind == "rglru":
-        return {"rglru": init_rglru_state(cfg, batch)}
+    if kind in ("ssm", "rglru"):
+        state = (init_ssm_state if kind == "ssm" else init_rglru_state)(
+            cfg, batch)
+        return {kind: jax.tree.map(
+            lambda a: jnp.broadcast_to(a, (count,) + a.shape).copy(), state)}
     if kind == "enc":
         return None
     raise ValueError(kind)
@@ -372,16 +382,9 @@ def _layer_cache(cfg: ModelConfig, kind: str, batch: int, max_len: int):
 def init_cache(cfg: ModelConfig, batch: int, max_len: int):
     groups = []
     for kinds, count in _stack_groups(cfg):
-        per_kind = []
-        for kind in kinds:
-            lc = _layer_cache(cfg, kind, batch, max_len)
-            if lc is None:
-                per_kind.append(None)
-            else:
-                per_kind.append(jax.tree.map(
-                    lambda a: jnp.broadcast_to(
-                        a, (count,) + a.shape).copy(), lc))
-        groups.append(tuple(per_kind) if any(
+        per_kind = tuple(_group_cache(cfg, kind, count, batch, max_len)
+                         for kind in kinds)
+        groups.append(per_kind if any(
             c is not None for c in per_kind) else None)
     return {"groups": groups, "pos": jnp.zeros((), jnp.int32)}
 
@@ -400,8 +403,7 @@ def prefill(cfg: ModelConfig, params: Params, batch, cache):
             return cross_kv(cfg, layer_params[0]["cross"], enc_out)
 
         xks, xvs = lax.map(fill, gp)
-        cg = cache["groups"][dec_group][0]
-        cg = dict(cg, xk=xks, xv=xvs)
+        cg = kv_cache.with_memory(cache["groups"][dec_group][0], xks, xvs)
         cache = dict(cache)
         cache["groups"] = list(cache["groups"])
         cache["groups"][dec_group] = (cg,)

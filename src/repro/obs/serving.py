@@ -120,8 +120,6 @@ def collectives(hlo_text: str) -> Dict[str, Tuple[int, int]]:
             for k, n in sorted(s.collective_count.items())}
 
 
-# a stacked K/V leaf's op_name among a step's parameters
-_KV_PARAM = re.compile(r"cache\[.*\[\\?'(?:k|v|xk|xv)\\?'\]")
 _CALLEE = re.compile(r"calls=%?([\w\.\-]+)")
 # kinds that make no buffer of their own (``copy-done``: its start has)
 _NO_BUFFER = {"parameter", "tuple", "get-tuple-element", "bitcast", "while",
@@ -139,20 +137,24 @@ def cache_relayouts(hlo_text: str) -> Tuple[int, int]:
     optimized HLO text (``compiled.as_text()``): copies, fusions, slices,
     transposes and reshapes of the cache.  Loop bodies count once per
     trip, as :func:`collectives` counts them, and shapes are one
-    device's.  The stacked K/V are the step's 5-D parameters named
-    ``cache[...]['k']`` (``'v'``, ``'xk'``, ``'xv'``); a buffer is one of
-    theirs when it holds as many elements as a layer of a leaf, or the
-    whole leaf, and has a d_head dim.  Writes into the stack in place
-    (a ``dynamic-update-slice``, or a fusion whose root is one) make no
-    buffer, nor do parameters, tuples and bitcasts."""
+    device's.  The stacked K/V are the step's parameters named
+    ``cache[...]['<leaf>']`` for a leaf of ``kv_cache.KV_LEAVES``; a
+    buffer is one of theirs when it holds as many elements as a layer of
+    a leaf, or the whole leaf, and has a d_head dim.  Writes into the
+    stack in place (a ``dynamic-update-slice``, or a fusion whose root is
+    one) make no buffer, nor do parameters, tuples and bitcasts."""
     from repro.launch.hlo_parse import _shape_bytes, parse_hlo, run_counts
+    from repro.models.kv_cache import KV_LEAVES
 
+    # a stacked K/V leaf's op_name among a step's parameters
+    kv_param = re.compile(r"cache\[.*\[\\?'(?:%s)\\?'\]"
+                          % "|".join(KV_LEAVES))
     comps = parse_hlo(hlo_text)
     d_head = {}  # elements of a layer of a leaf, or of the leaf -> d_head
     for _, kind, shapes, rest in comps["__entry__"].instructions:
         op = re.search(r'op_name="([^"]*)"', rest)
-        if (kind == "parameter" and op and _KV_PARAM.fullmatch(op.group(1))
-                and shapes and len(_dims(shapes[0][1])) == 5):
+        if (kind == "parameter" and op and kv_param.fullmatch(op.group(1))
+                and shapes):
             dims = _dims(shapes[0][1])
             d_head[math.prod(dims[1:])] = d_head[math.prod(dims)] = dims[-1]
 

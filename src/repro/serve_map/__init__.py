@@ -25,10 +25,10 @@ report) and ``python -m repro.serve_map serve`` (JSONL request/response
 loop over stdin/stdout).
 """
 from .bucket import ShapeBucketer
-from .request import MapRequest, MapResponse, model_requests
+from .request import MapRequest, MapResponse
 from .service import MappingService, ServiceStats
 
 __all__ = [
     "MapRequest", "MapResponse", "MappingService", "ServiceStats",
-    "ShapeBucketer", "model_requests",
+    "ShapeBucketer",
 ]

@@ -2,9 +2,9 @@
 
 A standardized workload API in the spirit of the MLPerf
 algorithmic-efficiency spec: a :class:`MapRequest` names *what* to map (an
-exact-shape einsum, or a whole model via :func:`model_requests`), *where*
-(the target :class:`~repro.core.arch.Arch`), *towards what* (the search
-objective) and *by when* (an optional per-request wall-clock deadline).
+exact-shape einsum), *where* (the target :class:`~repro.core.arch.Arch`),
+*towards what* (the search objective) and *by when* (an optional
+per-request wall-clock deadline).
 The :class:`MapResponse` carries the served mapping plus everything a
 caller needs to judge it: where the answer came from (exact hit / bucket
 hit / coalesced wait / budgeted search), the einsum it was actually
@@ -14,13 +14,13 @@ searched for (the bucket, when padded), and a certified optimality
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Dict, Optional
+from typing import Optional
 
 from repro.core.arch import Arch
 from repro.core.einsum import Einsum
 from repro.core.search import MapperStats, MappingResult, einsum_key
 
-__all__ = ["MapRequest", "MapResponse", "model_requests"]
+__all__ = ["MapRequest", "MapResponse"]
 
 
 @dataclass(frozen=True)
@@ -72,29 +72,3 @@ class MapResponse:
     latency_s: float = 0.0
     deadline_met: bool = True
     stats: Optional[MapperStats] = None
-
-
-def model_requests(cfg, arch: Arch, mode: str = "decode", batch: int = 1,
-                   seq: int = 1024, objective: str = "edp",
-                   deadline_s: Optional[float] = None,
-                   allow_bucketed: bool = True) -> Dict[str, MapRequest]:
-    """One request per *structurally unique* einsum of a model forward pass.
-
-    The extraction and dedup mirror the offline planner
-    (``repro.netmap``): repeated layers collapse onto one request, keyed
-    here by the first occurrence's einsum name.  Feed the values to
-    :meth:`MappingService.map` (or ``map_model``, which does exactly this).
-    """
-    from repro.netmap.extract import extract_einsums
-
-    out: Dict[str, MapRequest] = {}
-    seen = set()
-    for entry in extract_einsums(cfg, mode=mode, batch=batch, seq=seq):
-        k = einsum_key(entry.einsum)
-        if k in seen:
-            continue
-        seen.add(k)
-        out[entry.einsum.name] = MapRequest(
-            einsum=entry.einsum, arch=arch, objective=objective,
-            deadline_s=deadline_s, allow_bucketed=allow_bucketed)
-    return out

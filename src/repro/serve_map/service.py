@@ -60,7 +60,7 @@ from repro.netmap.cache import MappingCache, compute_key
 from repro.obs.tracer import CAT_SERVICE, active
 
 from .bucket import ShapeBucketer, validate_bucketed
-from .request import MapRequest, MapResponse, model_requests
+from .request import MapRequest, MapResponse
 
 __all__ = ["MappingService", "ServiceStats", "NoServableMappingError"]
 
@@ -238,18 +238,6 @@ class MappingService:
             resp = self._miss(req, search_einsum, skey,
                               bucketed=(search_einsum is not req.einsum), t0=t0)
         return self._finalize(req, resp, t0)
-
-    def map_model(self, cfg, arch, mode: str = "decode", batch: int = 1,
-                  seq: int = 1024, objective: str = "edp",
-                  deadline_s: Optional[float] = None,
-                  allow_bucketed: bool = True) -> Dict[str, MapResponse]:
-        """Map every structurally unique einsum of a model forward pass
-        (the online analogue of ``repro.netmap``'s offline planner).
-        Returns ``{einsum name: MapResponse}`` in execution order."""
-        reqs = model_requests(cfg, arch, mode=mode, batch=batch, seq=seq,
-                              objective=objective, deadline_s=deadline_s,
-                              allow_bucketed=allow_bucketed)
-        return {name: self.map(r) for name, r in reqs.items()}
 
     # -- internals ---------------------------------------------------------
 

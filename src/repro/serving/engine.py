@@ -6,23 +6,20 @@ and kv-heads over 'model' when divisible (else the KV sequence dim over
 'model').  Both steps donate the cache: the layer scan carries the stacked
 cache and writes only each step's new K/V rows into it, so XLA aliases the
 donated input to the output cache and updates it in place, with no copy of
-the whole cache.  The stacked K/V (L, B, Hkv, slots, Dh) keep one pinned
-layout (:func:`kv_layout`) from the program that makes them through every
+the whole cache.  The stacked K/V keep one pinned layout
+(``kv_cache.layout``) from the program that makes them through every
 step, and attention reads each chunk of slots where it lies, so no step
 relays the cache or a layer of it, on one chip or on several."""
 from __future__ import annotations
 
-from typing import Any, Dict, Optional, Tuple
-
 import jax
 import jax.numpy as jnp
-from jax.experimental.layout import Format, Layout
+from jax.experimental.layout import Format
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.distributed.sharding import shardings_for
-from repro.models import lm
+from repro.models import kv_cache, lm
 from repro.models.config import ModelConfig
-from repro.training.step import _abstract_init
 
 
 def _axis_size(mesh: Mesh, names) -> int:
@@ -42,26 +39,10 @@ def _div(x: int, mesh: Mesh, names) -> bool:
     return s > 1 and x % s == 0
 
 
-# the stacked K/V leaves of a cache (``lm._layer_cache``), each
-# (L, B, Hkv, slots, Dh); recurrent states keep the default layout
-KV_LEAVES = ("k", "v", "xk", "xv")
-LANES = 128  # a TPU tiles an array's two minor dims over (8, 128)
-
-
-def kv_layout(d_head: int) -> Layout:
-    """The order a stacked K/V leaf lies in, major to minor: d_head minor
-    where it fills whole lanes, else slots minor, so that no d_head (96)
-    is padded to the lane width.  The steps' products read a chunk of
-    slots in place either way, and each layer loop keeps this layout."""
-    if d_head % LANES == 0:
-        return Layout(major_to_minor=(0, 1, 2, 3, 4))
-    return Layout(major_to_minor=(0, 1, 2, 4, 3))
-
-
 def cache_shardings(cfg: ModelConfig, cache_abstract, mesh: Mesh):
     """Structural sharding for a cache pytree (built from abstract shapes).
     Each stacked K/V leaf gets a ``Format`` of its sharding and
-    :func:`kv_layout`, so the program that makes the cache and every step
+    ``kv_cache.layout``, so the program that makes the cache and every step
     that takes and returns it keep one layout."""
     batch_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
 
@@ -71,26 +52,27 @@ def cache_shardings(cfg: ModelConfig, cache_abstract, mesh: Mesh):
         if nd == 0:
             return NamedSharding(mesh, P())
         spec = [None] * nd
-        # stacked layer caches are (L, B, ...); states (L, B, ...)
+        # stacked layer caches and states are (L, B, ...)
         bdim = 1 if nd >= 2 else 0
         if _div(shp[bdim], mesh, batch_axes):
             spec[bdim] = batch_axes
-        if nd >= 4:
-            # (L, B, H, S, D) or (L, B, H, N, P): the head dim first
-            if _div(shp[2], mesh, "model"):
-                spec[2] = "model"
-            elif nd == 5 and _div(shp[3], mesh, "model"):
-                # GQA with kv_heads < model size: shard the KV sequence dim
-                # over 'model' instead (ring-attention-style cache layout)
-                spec[3] = "model"
-            if nd == 5 and spec[3] is None and shp[1] == 1 \
-                    and _div(shp[3], mesh, batch_axes):
-                # batch-1 long-context: shard the sequence dim over data
-                spec[3] = batch_axes
-        sharding = NamedSharding(mesh, P(*spec))
-        if nd == 5 and getattr(path[-1], "key", None) in KV_LEAVES:
-            return Format(kv_layout(shp[4]), sharding)
-        return sharding
+        if not kv_cache.is_kv(path):
+            if nd >= 4 and _div(shp[2], mesh, "model"):
+                spec[2] = "model"  # a state's heads: (L, B, H, N, P)
+            return NamedSharding(mesh, P(*spec))
+        heads, slots = kv_cache.HEADS, kv_cache.SLOTS
+        if _div(shp[heads], mesh, "model"):
+            spec[heads] = "model"
+        elif _div(shp[slots], mesh, "model"):
+            # GQA with kv_heads < model size: shard the slots over 'model'
+            # instead (ring-attention-style cache layout)
+            spec[slots] = "model"
+        if spec[slots] is None and shp[1] == 1 \
+                and _div(shp[slots], mesh, batch_axes):
+            # batch-1 long-context: shard the slots over data
+            spec[slots] = batch_axes
+        return Format(kv_cache.layout(cfg.d_head),
+                      NamedSharding(mesh, P(*spec)))
 
     return jax.tree_util.tree_map_with_path(one, cache_abstract)
 
@@ -107,11 +89,25 @@ def batch_shardings(mesh: Mesh, batch_abstract):
     return jax.tree.map(one, batch_abstract)
 
 
+def init_params(cfg: ModelConfig, mesh: Mesh, seed: int = 0,
+                mode: str = "tp"):
+    """Params made on ``mesh`` from ``seed``, each sharding gated on the
+    param's shape dividing the mesh axes; returns (params, specs)."""
+    key = jax.random.PRNGKey(seed)
+
+    def init_fn(key):
+        params, _ = lm.init(cfg, key)
+        return params
+
+    params_shape, specs = lm.abstract_init(cfg, key)
+    param_sh = shardings_for(specs, mesh, mode, like=params_shape)
+    return jax.jit(init_fn, out_shardings=param_sh)(key), specs
+
+
 def make_serve_steps(cfg: ModelConfig, mesh: Mesh, specs, cache_abstract,
                      batch_abstract, mode: str = "tp"):
-    # params placed as training.step.init_sharded places them: each
-    # sharding gated on the param's shape dividing the mesh axes
-    params_abs, _ = _abstract_init(cfg, jax.random.PRNGKey(0))
+    # params placed as init_params places them
+    params_abs, _ = lm.abstract_init(cfg, jax.random.PRNGKey(0))
     param_sh = shardings_for(specs, mesh, mode, like=params_abs)
     cache_sh = cache_shardings(cfg, cache_abstract, mesh)
     batch_sh = batch_shardings(mesh, batch_abstract)
@@ -141,25 +137,3 @@ def make_serve_steps(cfg: ModelConfig, mesh: Mesh, specs, cache_abstract,
         donate_argnums=(2,),
     )
     return prefill_step, decode_step, (param_sh, batch_sh, cache_sh, tok_sh)
-
-
-def decode_mapping_plan(cfg: ModelConfig, service, arch, batch: int,
-                        kv_len: int, objective: str = "edp",
-                        deadline_s: Optional[float] = None
-                        ) -> Dict[str, Any]:
-    """Per-decode-step mapping plan from the online mapper.
-
-    Queries the :class:`repro.serve_map.MappingService` for every
-    structurally unique einsum of one decode step at the *exact*
-    ``(batch, kv_len)`` shape — the KV length grows by one every step, so
-    consecutive steps collapse onto the service's shape buckets and only
-    bucket-boundary crossings pay a search.  Returns ``{einsum name:
-    MapResponse}``; each response carries the mapping, its provenance
-    (hit/bucket/search) and a certified ``gap_bound``.
-
-    Deliberately jax-free: safe to call from schedulers and admission
-    controllers without touching the sharded execution path.
-    """
-    return service.map_model(cfg, arch, mode="decode", batch=batch,
-                             seq=kv_len, objective=objective,
-                             deadline_s=deadline_s)

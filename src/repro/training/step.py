@@ -19,6 +19,7 @@ from repro.distributed.sharding import batch_pspec, shardings_for
 from repro.models import lm
 from repro.models.config import ModelConfig
 from repro.optim.adamw import OptConfig, apply_updates, init_opt_state, opt_state_specs
+from repro.serving.engine import init_params
 
 
 def make_train_step(cfg: ModelConfig, oc: OptConfig, mesh: Mesh,
@@ -85,33 +86,12 @@ def make_train_step(cfg: ModelConfig, oc: OptConfig, mesh: Mesh,
 def init_sharded(cfg: ModelConfig, oc: Optional[OptConfig], mesh: Mesh,
                  seed: int = 0, mode: str = "tp"):
     """Initialize params (and optionally optimizer state) sharded on-device."""
-    key = jax.random.PRNGKey(seed)
-
-    def init_fn(key):
-        params, _ = lm.init(cfg, key)
-        return params
-
-    params_shape, specs = _abstract_init(cfg, key)
-    param_sh = shardings_for(specs, mesh, mode, like=params_shape)
-    params = jax.jit(init_fn, out_shardings=param_sh)(key)
+    params, specs = init_params(cfg, mesh, seed=seed, mode=mode)
     if oc is None:
         return params, specs, None
-    opt_abs = jax.eval_shape(lambda p: init_opt_state(oc, p), params_shape)
+    opt_abs = jax.eval_shape(lambda p: init_opt_state(oc, p), params)
     opt_sh = shardings_for(opt_state_specs(oc, specs), mesh, mode,
                            like=opt_abs)
     opt_state = jax.jit(lambda p: init_opt_state(oc, p),
                         out_shardings=opt_sh)(params)
     return params, specs, opt_state
-
-
-def _abstract_init(cfg: ModelConfig, key):
-    """Shapes + specs without allocating (specs are trace-static)."""
-    specs_holder = {}
-
-    def run(k):
-        p, s = lm.init(cfg, k)
-        specs_holder["specs"] = s
-        return p
-
-    shapes = jax.eval_shape(run, key)
-    return shapes, specs_holder["specs"]

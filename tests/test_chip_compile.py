@@ -90,12 +90,12 @@ def qwen_decode(one_chip):
     from repro.launch.mesh import make_elastic_mesh
     from repro.models import lm
     from repro.serving.engine import make_serve_steps
-    from repro.training.step import _abstract_init
+    from repro.models.lm import abstract_init
 
     cfg = get_config("qwen1.5-0.5b")
     B, P, G = 8, 1024, 32
     mesh = make_elastic_mesh(target_model=1, devices=[one_chip])
-    params_abs, specs = _abstract_init(cfg, jax.random.PRNGKey(0))
+    params_abs, specs = abstract_init(cfg, jax.random.PRNGKey(0))
     cache_abs = jax.eval_shape(lambda: lm.init_cache(cfg, B, P + G))
     batch_abs = {"tokens": jax.ShapeDtypeStruct((B, P), jnp.int32)}
     _, decode, (param_sh, _, cache_sh, tok_sh) = make_serve_steps(
@@ -146,11 +146,11 @@ def _served_steps(cfg, devices, B, P, slots):
     from repro.launch.mesh import make_elastic_mesh
     from repro.models import lm
     from repro.serving.engine import make_serve_steps
-    from repro.training.step import _abstract_init
+    from repro.models.lm import abstract_init
 
     cfg = dataclasses.replace(cfg, param_dtype="bfloat16", dtype="bfloat16")
     mesh = make_elastic_mesh(target_model=len(devices), devices=devices)
-    params_abs, specs = _abstract_init(cfg, jax.random.PRNGKey(0))
+    params_abs, specs = abstract_init(cfg, jax.random.PRNGKey(0))
     cache_abs = jax.eval_shape(lambda: lm.init_cache(cfg, B, slots))
     batch_abs = {"tokens": jax.ShapeDtypeStruct((B, P), jnp.int32)}
     prefill, decode, (param_sh, batch_sh, cache_sh, tok_sh) = \

@@ -138,11 +138,20 @@ def test_serve_cli_smoke():
 
 
 def test_compile_cache_dir(tmp_path, monkeypatch):
+    from jax.experimental.compilation_cache import compilation_cache
+
     from repro.launch.compile_cache import DEFAULT_DIR, use_compile_cache
     before = jax.config.jax_compilation_cache_dir
+    enabled = jax.config.jax_enable_compilation_cache
     try:
-        # a directory named by the environment is JAX's to read
+        # on the CPU the cache stays off, whatever the environment names
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert use_compile_cache() is None
+        assert jax.config.jax_enable_compilation_cache is False
+        assert jax.config.jax_compilation_cache_dir == before
+        jax.config.update("jax_enable_compilation_cache", enabled)
+        # on a chip, a directory named by the environment is JAX's to read
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         assert use_compile_cache() == str(tmp_path)
         assert jax.config.jax_compilation_cache_dir == before
         # else one fixed directory at the checkout root
@@ -150,5 +159,27 @@ def test_compile_cache_dir(tmp_path, monkeypatch):
         assert use_compile_cache() == str(DEFAULT_DIR)
         assert jax.config.jax_compilation_cache_dir == str(DEFAULT_DIR)
         assert (DEFAULT_DIR.parent / "src" / "repro").is_dir()
+        assert jax.config.jax_enable_compilation_cache == enabled
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
+        jax.config.update("jax_enable_compilation_cache", enabled)
+        compilation_cache.reset_cache()
+
+
+def test_serve_twice_with_one_compile_cache(tmp_path):
+    """A second run finds the first's compilation cache directory and
+    still serves: on the CPU no executable is read back from it (one read
+    back loses the K/V leaves' pinned layout and fails the first decode)."""
+    import os
+    import subprocess
+    import sys
+
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"),
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0")
+    for run in range(2):
+        r = subprocess.run(
+            [sys.executable, "-m", "repro.launch.serve", "--arch",
+             "phi3-mini-3.8b", "--smoke", "--gen", "4"],
+            capture_output=True, text=True, env=env, timeout=300)
+        assert r.returncode == 0, (run, r.stderr[-2000:])

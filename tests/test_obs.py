@@ -449,12 +449,12 @@ def served_programs():
     from repro.launch.mesh import make_elastic_mesh
     from repro.models import lm
     from repro.serving.engine import make_serve_steps
-    from repro.training.step import _abstract_init
+    from repro.models.lm import abstract_init
 
     cfg = get_config("phi3-mini-3.8b", smoke=True)
     B, P, slots = 2, 16, 32
     mesh = make_elastic_mesh(target_model=1, devices=jax.devices()[:1])
-    params_abs, specs = _abstract_init(cfg, jax.random.PRNGKey(0))
+    params_abs, specs = abstract_init(cfg, jax.random.PRNGKey(0))
     cache_abs = jax.eval_shape(lambda: lm.init_cache(cfg, B, slots))
     batch_abs = {"tokens": jax.ShapeDtypeStruct((B, P), jnp.int32)}
     prefill, decode, (param_sh, batch_sh, cache_sh, tok_sh) = \
