@@ -10,6 +10,10 @@ HLO text ourselves:
                 by the constant their condition's ROOT compares the
                 counter against with ``direction=LT``, as JAX's scans and
                 fori_loops lower: the TPU compiler keeps no trip count).
+                A loop bounded by data, whose condition compares with no
+                constant (attention's KV loop over a stacked cache),
+                counts as one trip: its body once per trip of the loop
+                around it.
   * bytes     — HBM-traffic estimate: sum of operand + output buffer sizes
                 at fusion/op boundaries (slicing ops read only the slice).
   * collective_bytes — operand sizes of all-gather / all-reduce /
@@ -201,7 +205,8 @@ def parse_hlo(text: str) -> Dict[str, Computation]:
             cond = re.search(r"condition=%?([\w\.\-]+)", rest)
             if tc:
                 trip = float(tc.group(1))
-            else:  # callees print before their callers: cond is parsed
+            else:  # callees print before their callers: cond is parsed;
+                # a bound that is data counts as one trip
                 c = comps.get(cond.group(1)) if cond else None
                 trip = (c.lt_bound if c else None) or 1.0
             if body:

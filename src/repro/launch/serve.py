@@ -23,7 +23,8 @@ from repro.configs import get_config
 from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_elastic_mesh
 from repro.models import lm
-from repro.obs.serving import WATCH, cache_relayouts, collectives
+from repro.obs.serving import (WATCH, attend_chunk_share, cache_relayouts,
+                                collectives)
 from repro.serving.engine import init_params, make_serve_steps
 
 
@@ -45,6 +46,10 @@ class ServeRun:
     # of it makes that hold a layer's K or V or a whole stacked K/V leaf
     cache_relayouts: Dict[str, Tuple[int, int]] = field(
         default_factory=dict)
+    # per step, the share of (query block, KV chunk) pairs a causal
+    # attention layer visits, of every allocated chunk; decode's averaged
+    # over the run's steps
+    attend_chunk_share: Dict[str, float] = field(default_factory=dict)
 
 
 def generate(cfg, mesh, params, specs, batch, gen: int, mode: str = "tp",
@@ -55,7 +60,8 @@ def generate(cfg, mesh, params, specs, batch, gen: int, mode: str = "tp",
     tokens (a VLM's image embeddings).
     """
     B, P = batch["tokens"].shape
-    init_cache = partial(lm.init_cache, cfg, B, P + gen + extra_len)
+    rows, slots = P + extra_len, P + gen + extra_len
+    init_cache = partial(lm.init_cache, cfg, B, slots)
     prefill_step, decode_step, (_, _, cache_sh, _) = make_serve_steps(
         cfg, mesh, specs, jax.eval_shape(init_cache), batch, mode=mode)
     cache = jax.jit(init_cache, out_shardings=cache_sh)()
@@ -72,6 +78,11 @@ def generate(cfg, mesh, params, specs, batch, gen: int, mode: str = "tp",
     counted = ({name: collectives(text) for name, text in texts.items()}
                if mesh.devices.size > 1 else {})
     relaid = {name: cache_relayouts(text) for name, text in texts.items()}
+    visited = {"prefill": attend_chunk_share(0, rows, slots)}
+    if gen > 1:
+        visited["decode"] = sum(
+            attend_chunk_share(fill, 1, slots)
+            for fill in range(rows, rows + gen - 1)) / (gen - 1)
 
     t0 = time.perf_counter()
     last, cache = prefill_step(params, batch, cache)
@@ -92,7 +103,8 @@ def generate(cfg, mesh, params, specs, batch, gen: int, mode: str = "tp",
         batch=batch, tokens=jnp.concatenate(out_tokens, axis=1), logits=out_logits,
         compile_s=compile_s, prefill_s=prefill_s,
         decode_s_per_step=t_decode / (gen - 1) if gen > 1 else float("nan"),
-        collectives=counted, cache_relayouts=relaid)
+        collectives=counted, cache_relayouts=relaid,
+        attend_chunk_share=visited)
 
 
 def main(argv=None, devices=None):
@@ -147,6 +159,8 @@ def main(argv=None, devices=None):
     for name, (n, b) in run.cache_relayouts.items():
         print(f"cache relayouts per {name} step: {n} x, "
               f"{b / 1e6:.3f} MB per device")
+    for name, share in run.attend_chunk_share.items():
+        print(f"attend chunk share per {name} step: {share:.3f}")
     print(f"gc: {'/'.join(map(str, gc_run.collections))} collections "
           f"(generations 0/1/2), {gc_run.pause_s * 1e3:.3f} ms paused, "
           "compile included")

@@ -102,6 +102,23 @@ def _mask_for(cfgt, q_pos, k_pos, kv_valid):
     return mask  # (qc, kc)
 
 
+CHUNK = 512  # flash_attention's query and KV chunk, in rows and slots
+
+
+def visible_chunks(q_off, qi, q_chunk, kv_valid, kv_chunk, slots, causal):
+    """How many KV chunks, from the first, the cached loop visits for query
+    block ``qi``: those that hold a key some query of the block can see,
+    below the fill ``kv_valid`` and, when causal, at or before the block's
+    last position.  The chunks past it hold none; chunk 0 is always seen,
+    so they would add exactly 0.  A window that binds comes with a ring
+    cache, whose loop is not causal, so no chunk is cut from the front.
+    Takes ints or traced scalars."""
+    last = jnp.minimum(kv_valid, slots)
+    if causal:
+        last = jnp.minimum(last, q_off + (qi + 1) * q_chunk)
+    return -(-last // kv_chunk)
+
+
 def _kv_chunks(x, kv_chunk):
     """(B, Hkv, Sk, Dh) -> (Sk // kv_chunk, B, Hkv, kv_chunk, Dh): each
     chunk keeps the (slots, d_head) tiles of its heads."""
@@ -125,7 +142,6 @@ def _flash_fwd_impl(cfgt, q, k, v, q_off_f, kv_valid_f, layer=None):
             kblk, vblk, ci = xs
             return kblk, vblk, ci * kv_chunk + jnp.arange(kv_chunk)
     else:  # a stacked cache, each chunk read from it in the loop
-        kv_xs = jnp.arange(-(-Sk0 // kv_chunk))
         read = partial(kv_cache.chunk, k, v, layer, kv_chunk)
     qcs = jnp.moveaxis(q.reshape(B, nq, q_chunk, Hkv, rep, Dh), 1, 0)
     # context parallelism must survive the chunking reshape: shard the
@@ -159,7 +175,13 @@ def _flash_fwd_impl(cfgt, q, k, v, q_off_f, kv_valid_f, layer=None):
         m0 = jnp.full((B, Hkv, rep, q_chunk), -jnp.inf, jnp.float32)
         l0 = jnp.zeros((B, Hkv, rep, q_chunk), jnp.float32)
         a0 = jnp.zeros((B, Hkv, rep, q_chunk, Dh), jnp.float32)
-        (m, l, acc), _ = lax.scan(kv_step, (m0, l0, a0), kv_xs)
+        if layer is None:
+            (m, l, acc), _ = lax.scan(kv_step, (m0, l0, a0), kv_xs)
+        else:  # no backward here, so the trip count may follow the data
+            hi = visible_chunks(q_off, qi, q_chunk, kv_valid, kv_chunk, Sk0,
+                                causal)
+            m, l, acc = lax.fori_loop(0, hi, lambda ci, c: kv_step(c, ci)[0],
+                                      (m0, l0, a0))
         l = jnp.maximum(l, 1e-30)
         out = jnp.einsum("bgrqd->bqgrd",
                          acc / l[..., None]).astype(q.dtype)
@@ -275,7 +297,7 @@ _flash.defvjp(_flash_fwd, _flash_bwd_impl)
 
 @jax.named_scope(ATTEND)
 def flash_attention(q, k, v, *, causal: bool, window: int = 0,
-                    q_offset=0, q_chunk: int = 512, kv_chunk: int = 512,
+                    q_offset=0, q_chunk: int = CHUNK, kv_chunk: int = CHUNK,
                     kv_valid=None, layer=None):
     """Streaming softmax attention, chunked over q and kv, with a manual
     flash backward (custom_vjp).
@@ -283,7 +305,8 @@ def flash_attention(q, k, v, *, causal: bool, window: int = 0,
     q: (B, Sq, Hq, Dh); k/v: (B, Sk, Hkv, Dh), or, with ``layer``, the
     stacked K/V of a ``kv_cache``: the KV loop then reads layer
     ``layer``'s chunks straight from the stack (``kv_cache.chunk``), with
-    no copy of the layer and no backward.  GQA: Hq % Hkv == 0.
+    no copy of the layer and no backward, and stops after the last chunk
+    a query block can see (``visible_chunks``).  GQA: Hq % Hkv == 0.
     ``q_offset`` is the absolute position of q[0] relative to k[0] (decode
     with a cache passes the fill index).  Peak live block is
     (B, Hkv, rep, q_chunk, kv_chunk) in f32.  Returns (B, Sq, Hq, Dh).

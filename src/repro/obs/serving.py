@@ -29,7 +29,8 @@ issues, by kind, from the program's text: on a mesh of several chips,
 the all-reduces of tensor parallelism and anything that moves the cache.
 :func:`cache_relayouts` counts, the same way, the buffers it makes that
 hold a layer's K or V or a whole stacked K/V leaf: what reading the
-cache where it lies avoids.
+cache where it lies avoids.  :func:`attend_chunk_share` says how much of
+the cache a step's attention visits.
 
 Nothing here imports JAX at import time.
 """
@@ -183,3 +184,19 @@ def cache_relayouts(hlo_text: str) -> Tuple[int, int]:
             count += runs.get(name, 0.0) * len(made)
             nbytes += runs.get(name, 0.0) * sum(made)
     return round(count), round(nbytes)
+
+
+def attend_chunk_share(fill: int, rows: int, slots: int) -> float:
+    """The share of (query block, KV chunk) pairs that one step's causal
+    attention over a stacked cache visits, of those a loop over every
+    allocated chunk would: ``rows`` new rows at fill ``fill`` in a cache
+    of ``slots``, chunked as ``attention_block`` has ``flash_attention``
+    chunk them.  It asks ``layers.visible_chunks``, the bound the loop
+    itself takes."""
+    from repro.models.layers import CHUNK, visible_chunks
+
+    kv_chunk, q_chunk = min(CHUNK, slots), min(CHUNK, rows)
+    blocks = -(-rows // q_chunk)
+    seen = sum(int(visible_chunks(fill, qi, q_chunk, fill + rows, kv_chunk,
+                                  slots, True)) for qi in range(blocks))
+    return seen / (blocks * -(-slots // kv_chunk))

@@ -1,11 +1,15 @@
 """flash_attention (pure-JAX, custom_vjp) vs naive attention: fwd + grads."""
 import math
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import lax
 
+from repro.models import kv_cache, layers
 from repro.models.layers import flash_attention
 
 
@@ -93,3 +97,89 @@ def test_kv_valid_masks_tail():
     out2 = flash_attention(q, k_g, v_g, causal=False, kv_valid=20, q_chunk=8,
                            kv_chunk=8)
     np.testing.assert_allclose(np.asarray(out1), np.asarray(out2), rtol=1e-6)
+
+
+def _every_chunk_fwd(cfgt, q, k, v, q_off_f, kv_valid_f, layer):
+    """The cached forward as it was before its KV loop took a bound: a
+    scan over every allocated chunk of the stack, the chunks that no
+    query sees masked to nothing."""
+    causal, window, q_chunk, kv_chunk, Sk = cfgt
+    assert not window
+    B, Sq, Hkv, rep, Dh = q.shape
+    q_off = q_off_f.astype(jnp.int32)
+    kv_valid = kv_valid_f.astype(jnp.int32)
+    qcs = jnp.moveaxis(q.reshape(B, Sq // q_chunk, q_chunk, Hkv, rep, Dh),
+                       1, 0)
+
+    def q_block(qi_blk):
+        qi, qblk = qi_blk
+        qb = (qblk * (1.0 / math.sqrt(Dh))).astype(q.dtype)
+        q_pos = q_off + qi * q_chunk + jnp.arange(q_chunk)
+
+        def kv_step(carry, ci):
+            m, l, acc = carry
+            kblk, vblk, k_pos = kv_cache.chunk(k, v, layer, kv_chunk, ci)
+            s = jnp.einsum("bqgrd,bgkd->bgrqk", qb, kblk,
+                           preferred_element_type=jnp.float32)
+            mask = k_pos[None, :] < kv_valid
+            if causal:
+                mask = mask & (k_pos[None, :] <= q_pos[:, None])
+            s = jnp.where(mask[None, None, None], s, -1e30)
+            m_new = jnp.maximum(m, s.max(axis=-1))
+            p = jnp.exp(s - m_new[..., None])
+            corr = jnp.exp(m - m_new)
+            pv = jnp.einsum("bgrqk,bgkd->bgrqd", p.astype(q.dtype), vblk,
+                            preferred_element_type=jnp.float32)
+            return (m_new, l * corr + p.sum(axis=-1),
+                    acc * corr[..., None] + pv), None
+
+        init = (jnp.full((B, Hkv, rep, q_chunk), -jnp.inf, jnp.float32),
+                jnp.zeros((B, Hkv, rep, q_chunk), jnp.float32),
+                jnp.zeros((B, Hkv, rep, q_chunk, Dh), jnp.float32))
+        (_, l, acc), _ = lax.scan(kv_step, init,
+                                  jnp.arange(-(-Sk // kv_chunk)))
+        out = acc / jnp.maximum(l, 1e-30)[..., None]
+        return jnp.einsum("bgrqd->bqgrd", out).astype(q.dtype)
+
+    outs = lax.map(q_block, (jnp.arange(Sq // q_chunk), qcs))
+    return jnp.moveaxis(outs, 0, 1).reshape(B, Sq, Hkv, rep, Dh), None
+
+
+@pytest.mark.parametrize("hq,hkv,slots,fill,rows,causal,kv_valid", [
+    (2, 2, 2048, 0, 1536, True, None),     # causal prefill, 3 of 4 chunks
+    (2, 2, 2048, 0, 700, True, None),      # a prompt the chunk does not divide
+    (2, 2, 2048, 600, 700, True, None),    # a prefill after a fill
+    (2, 2, 2048, 510, 1, True, None),      # decode just below a boundary
+    (2, 2, 2048, 511, 1, True, None),      # the fill at a boundary
+    (2, 2, 2048, 512, 1, True, None),      # just past it
+    (6, 2, 2048, 1100, 1, True, None),     # GQA decode
+    (4, 2, 2048, 0, 1024, True, None),     # GQA prefill
+    (2, 2, 1024, 300, 1, False, 301),      # a ring cache still filling
+    (2, 2, 1024, 5000, 1, False, 1024),    # a full ring
+    (2, 2, 1000, 700, 1, True, None),      # overlapping last chunk, decode
+    (2, 2, 1000, 0, 900, True, None),      # and prefill
+])
+def test_cached_loop_stops_at_the_last_visible_chunk(
+        monkeypatch, hq, hkv, slots, fill, rows, causal, kv_valid):
+    """The cached KV loop, stopping after the last chunk that a query
+    block can see, gives what the loop over every allocated chunk gave:
+    causal prefill and decode, GQA and the padded one-row MHA decode, a
+    ring before and after it fills, and slots that the chunks do not
+    divide (the last chunk overlaps the one before)."""
+    B, L, Dh, layer = 2, 2, 16, 1
+    rng = np.random.default_rng(slots + fill + rows)
+    q = jnp.asarray(rng.normal(size=(B, rows, hq, Dh)), jnp.float32)
+    ck, cv = (jnp.asarray(rng.normal(size=(L, B, hkv, slots, Dh)),
+                          jnp.float32) for _ in "kv")
+    valid = fill + rows if kv_valid is None else kv_valid
+
+    def run():  # traced anew, with the forward the module holds now
+        attend = jax.jit(partial(flash_attention, causal=causal))
+        return np.asarray(attend(q, ck, cv, q_offset=jnp.int32(fill),
+                                 kv_valid=jnp.int32(valid),
+                                 layer=jnp.int32(layer)))
+
+    got = run()
+    monkeypatch.setattr(layers, "_flash_fwd_impl", _every_chunk_fwd)
+    want = run()
+    np.testing.assert_allclose(got, want, rtol=0, atol=1e-6)

@@ -579,6 +579,53 @@ def test_serve_prints_collectives_on_a_mesh_of_four(tmp_path):
     assert [ln.split(" step:")[0] for ln in r.stdout.splitlines()
             if ln.startswith("cache relayouts per ")] == [
         "cache relayouts per prefill", "cache relayouts per decode"]
+    # 8 + 3 slots are one chunk, which every step visits
+    assert [ln for ln in r.stdout.splitlines()
+            if ln.startswith("attend chunk share per ")] == [
+        "attend chunk share per prefill step: 1.000",
+        "attend chunk share per decode step: 1.000"]
+
+
+def test_a_data_bounded_loop_counts_once_per_trip_of_the_loop_around_it():
+    """A loop whose trip count is data (its condition compares the counter
+    with a value, not a constant, as attention's KV loop over a stacked
+    cache does) counts as one trip: its body once per trip of the loop
+    around it, here a scan of 3."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    from repro.launch.hlo_parse import parse_hlo, run_counts, summarize
+
+    def f(x, n):
+        def outer(c, _):
+            return lax.fori_loop(0, n, lambda i, y: jnp.tanh(y @ x), c), None
+        return lax.scan(outer, x, None, length=3)[0]
+
+    x = jnp.ones((8, 8), jnp.float32)
+    text = jax.jit(f).lower(x, 5).compile().as_text()
+    comps = parse_hlo(text)
+    body, = [name for name, c in comps.items() if name != "__entry__"
+             and any(kind == "dot" for _, kind, _, _ in c.instructions)]
+    assert run_counts(comps)[body] == 3
+    assert summarize(text).flops == 3 * 2 * 8 ** 3
+
+
+@pytest.mark.parametrize("fill,rows,slots,share", [
+    (0, 1536, 2048, 6 / 12),   # phi3 prefill: the causal lower triangle
+    (1024, 1, 2048, 3 / 4),    # phi3 decode, fill 1024-1151
+    (1151, 1, 2048, 3 / 4),
+    (1536, 1, 2048, 1.0),      # the prefill cell's decode steps
+    (512, 1, 4096, 2 / 8),     # Yi decode, fill 512-639
+    (639, 1, 4096, 2 / 8),
+    (0, 512, 4096, 1 / 8),     # Yi prefill
+])
+def test_attend_chunk_share_of_each_cells_steps(fill, rows, slots, share):
+    """``attend_chunk_share`` counts the chunks each query block visits,
+    by the loop's own bound, against every allocated chunk."""
+    from repro.obs.serving import attend_chunk_share
+
+    assert attend_chunk_share(fill, rows, slots) == share
 
 
 # a stacked K leaf (4 layers of (2, 3, 8, 16)) among a step's parameters,
