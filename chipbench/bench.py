@@ -1,37 +1,38 @@
 """Finds a cell's data by name: ``BENCHMARK.json``, the configuration file,
-the traffic mix, the correctness limits and the per-layer metric readers.
+its model family, the traffic mix, the correctness limits and the
+per-layer metric readers.
 
-Everything that belongs to one configuration, traffic mix, cell or metric
-is a file of its own, found by the name ``BENCHMARK.json`` gives it:
+Everything that belongs to one configuration, model family, traffic mix,
+cell or metric is a file of its own, found by the name ``BENCHMARK.json``
+or the configuration gives it:
 
     <config file>                          as BENCHMARK.json's ``file``
+    chipbench/families/<family>.py         the config's ``family``: below
     chipbench/traffic/<traffic>.json       the mix the generator reads
     chipbench/checks/<workload>.json       sample size and limits of ``correct``
     chipbench/metrics/<metric>.py          ``read(records)``; '.' -> '_'
+
+A family module owns all that depends on the architecture:
+``PUBLISHED_TO_PROGRAM`` (published key -> program field),
+``contradictions(config, cfg)`` and ``refuse_length(config, longest)``;
+its reference, ``Shape.from_config(config)`` and ``logits(view, shape,
+tokens, first, precision)``, written with ``chipbench/reference.py``;
+``ReferenceView(params, d_model, device)``, the program's weights in
+published form; ``WEIGHTS``, rules for leaves of its own
+(``chipbench/weights.py``); and ``prefill(config, batch, prompt)`` and
+``decode(config, batch, filled)``, each a ``counts.Work``.
 """
 from __future__ import annotations
 
 import importlib.util
 import json
 from dataclasses import dataclass, replace
+import sys
 from pathlib import Path
+from types import ModuleType
 from typing import Callable, Dict, List, Mapping, Optional
 
 ROOT = Path(__file__).resolve().parents[1]
-
-# published config key -> repro ModelConfig field
-PUBLISHED_TO_PROGRAM = {
-    "hidden_size": "d_model",
-    "intermediate_size": "d_ff",
-    "num_attention_heads": "n_heads",
-    "num_key_value_heads": "n_kv_heads",
-    "num_hidden_layers": "n_layers",
-    "vocab_size": "vocab",
-    "rms_norm_eps": "norm_eps",
-    "rope_theta": "rope_theta",
-    "tie_word_embeddings": "tie_embeddings",
-    "torch_dtype": "param_dtype",
-}
 
 
 class SpecError(ValueError):
@@ -44,6 +45,7 @@ class Cell:
     name: str
     chips: int
     config: Dict
+    family: ModuleType
     traffic: Dict
     check: Dict
     end_to_end: List[Dict]
@@ -76,36 +78,37 @@ def load_cell(workload: str, root: Path = ROOT) -> Cell:
     w = cells[workload]
     configs = {c["name"]: c for c in bench["configs"]}
     config = _read_json(root / configs[w["config"]]["file"])
+    if "family" not in config:
+        raise SpecError(f"configuration {w['config']!r} names no family")
     traffic = _read_json(root / "chipbench" / "traffic"
                          / f"{w['traffic']}.json")
     check = _read_json(root / "chipbench" / "checks" / f"{workload}.json")
     cell = Cell(
         name=workload, chips=int(w["chips"]), config=config,
-        traffic=traffic, check=check,
+        family=family(config["family"], root), traffic=traffic, check=check,
         end_to_end=[m for m in bench["end_to_end"] if _applies(m, workload)],
         per_layer=[m for m in bench["per_layer"] if _applies(m, workload)],
         root=root)
-    window = config.get("sliding_window")
-    if window and cell.longest > window:
-        raise SpecError(f"{workload}: {cell.longest} positions exceed the "
-                        f"{window}-position sliding window, which the "
-                        "program does not implement")
+    refused = cell.family.refuse_length(config, cell.longest)
+    if refused:
+        raise SpecError(f"{workload}: {refused}")
     if cell.longest > traffic["cache_slots"]:
         raise SpecError(f"{workload}: {cell.longest} positions do not fit "
                         f"{traffic['cache_slots']} cache slots")
     return cell
 
 
-def model_config(config: Mapping):
+def model_config(config: Mapping, family: ModuleType):
     """The program's ``ModelConfig`` for a configuration file: the
     ``repro.configs`` entry with the file's overrides, checked against
-    every published number the file states."""
+    every published number the file states, as ``family`` maps it, and
+    against what the family's reference describes."""
     from repro.configs import get_config
 
     cfg = replace(get_config(config["program_arch"]), **config["overrides"])
     wrong = [f"{k}={config[k]!r} but the program has {p}="
              f"{getattr(cfg, p)!r}"
-             for k, p in PUBLISHED_TO_PROGRAM.items()
+             for k, p in family.PUBLISHED_TO_PROGRAM.items()
              if k in config and getattr(cfg, p) != config[k]]
     d_head = config["hidden_size"] // config["num_attention_heads"]
     if cfg.d_head != config.get("head_dim", d_head):
@@ -114,12 +117,27 @@ def model_config(config: Mapping):
     if cfg.dtype != config["torch_dtype"]:
         wrong.append(f"activations in {cfg.dtype}, not the served "
                      f"{config['torch_dtype']}")
-    if (cfg.family, cfg.qkv_bias, cfg.n_experts, cfg.window) != (
-            "dense", False, 0, 0):
-        wrong.append("not the plain dense decoder the reference describes")
+    wrong += family.contradictions(config, cfg)
     if wrong:
         raise SpecError(f"{config['name']}: " + "; ".join(wrong))
     return cfg
+
+
+def _load(path: Path, name: str) -> ModuleType:
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    # dataclasses look their module up while the file runs
+    sys.modules[name] = mod
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def family(name: str, root: Path = ROOT) -> ModuleType:
+    """The module of model family ``name``, from its own file."""
+    path = root / "chipbench" / "families" / f"{name}.py"
+    if not path.exists():
+        raise SpecError(f"no model family {name!r} at {path}")
+    return _load(path, f"chipbench_family_{name}")
 
 
 def metric_reader(root: Path, name: str) -> Callable[[Mapping],
@@ -128,11 +146,7 @@ def metric_reader(root: Path, name: str) -> Callable[[Mapping],
     path = root / "chipbench" / "metrics" / f"{name.replace('.', '_')}.py"
     if not path.exists():
         raise SpecError(f"no reader for per-layer metric {name!r} at {path}")
-    spec = importlib.util.spec_from_file_location(
-        f"chipbench_metric_{path.stem}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load(path, f"chipbench_metric_{path.stem}").read
 
 
 def peaks(device_kind: str) -> Dict:

@@ -37,17 +37,16 @@ def _seeds(text: str):
 
 
 def readings(workload, seeds, control_seeds, controls, root=ROOT):
-    from chipbench import bench, cell, check, reference, traffic, weights
+    from chipbench import bench, cell, check, traffic
 
     from repro.launch.compile_cache import use_compile_cache
 
     c = bench.load_cell(workload, root)
     devices, _ = cell.device_check(c.chips)
     use_compile_cache()
-    cfg = bench.model_config(c.config)
+    cfg = bench.model_config(c.config, c.family)
     mix = c.traffic
     server = cell.Server(c, cfg, devices)
-    shape = reference.Shape.from_config(c.config)
     half = mix["batch"] // 2
     batches = max(1, math.ceil(c.check["rows"] / 2 / half))
     for seed in seeds:
@@ -61,10 +60,10 @@ def readings(workload, seeds, control_seeds, controls, root=ROOT):
             server.serve(prompts(b), mix["decode_steps"], served)
         picks = check.sample(seed, batches, mix["batch"], c.check["rows"])
         seqs, tokens = check.gather(prompts, served.tokens, picks)
-        view = weights.ReferenceView(server.params, cfg.d_model, devices[0])
-        got = check.compare(view, shape, seqs, tokens, c.check["ref_rows"],
+        ref = cell.reference(c, server.params, cfg.d_model, devices[0])
+        got = check.compare(ref, seqs, tokens, c.check["ref_rows"],
                             controls if seed in control_seeds else ())
-        del view  # it holds the weights: free them before the next seed's
+        del ref  # it holds the weights: free them before the next seed's
         server.params = None
         got["seed"] = seed
         yield got

@@ -6,7 +6,8 @@ The program is driven only through what ``repro.launch.serve`` uses:
 ``repro.models.lm.init_cache`` and ``repro.serving.engine.make_serve_steps``,
 whose ``prefill_step`` and ``decode_step`` the window times.  The weights
 are the benchmark's own (``weights.make``), placed with the shardings
-``make_serve_steps`` returns.
+``make_serve_steps`` returns, and the cell's model family (``bench``)
+gives the reference, the weight rules and the work counts.
 
 Each batch: B prompts drawn from the seed are prefilled and their first
 tokens come to the host; then ``decode_steps`` greedy steps run, each timed
@@ -29,7 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from chipbench import bench, check, counts, reference, traffic, weights
+from chipbench import bench, check, traffic, weights
 from chipbench import trace as trace_mod
 
 BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
@@ -113,6 +114,7 @@ class Server:
 
         mix = cell.traffic
         self.mix = mix
+        self.rules = cell.family.WEIGHTS
         B, P = mix["batch"], mix["prompt_tokens"]
         mesh = make_elastic_mesh(
             target_model=cell.config["mesh"]["model"], devices=devices)
@@ -137,7 +139,7 @@ class Server:
 
     def load(self, seed: int, d_model: int):
         self.params = weights.make(self.params_abs, self.param_sh, seed,
-                                   d_model)
+                                   d_model, self.rules)
         jax.block_until_ready(self.params)
 
     def serve(self, prompts: np.ndarray, steps: int,
@@ -180,10 +182,10 @@ def _memory_peak(devices) -> int:
 def _traced_works(cell: bench.Cell, batches: int) -> Dict[str, list]:
     """Needed work per chip of each step of ``batches`` batches, in the
     order served."""
-    mix, dims = cell.traffic, counts.Dims.from_config(cell.config)
+    mix, fam, config = cell.traffic, cell.family, cell.config
     B, P, G = mix["batch"], mix["prompt_tokens"], mix["decode_steps"]
-    pre = counts.prefill(dims, B, P).per_chip(cell.chips)
-    dec = [counts.decode(dims, B, P + i + 1).per_chip(cell.chips)
+    pre = fam.prefill(config, B, P).per_chip(cell.chips)
+    dec = [fam.decode(config, B, P + i + 1).per_chip(cell.chips)
            for i in range(G)]
     return {"prefill": [pre] * batches, "decode": dec * batches}
 
@@ -262,7 +264,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
     use_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     counter = _compile_counter()
-    cfg = bench.model_config(cell.config)
+    cfg = bench.model_config(cell.config, cell.family)
     mix = cell.traffic
     server = Server(cell, cfg, devices)
     server.load(seed, cfg.d_model)
@@ -326,11 +328,19 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
     return result
 
 
+def reference(cell: bench.Cell, params, d_model: int,
+              device) -> check.Reference:
+    """The family's float32 reference over ``params`` in published form,
+    on ``device``."""
+    fam = cell.family
+    view = fam.ReferenceView(params, d_model, device)
+    return partial(fam.logits, view, fam.Shape.from_config(cell.config))
+
+
 def _check(cell, server, cfg, device, seed, served, prompts) -> Dict:
     picks = check.sample(seed, len(served.tokens), cell.traffic["batch"],
                          cell.check["rows"])
     seqs, tokens = check.gather(lambda b: prompts(traffic.WINDOW, b),
                                 served.tokens, picks)
-    view = weights.ReferenceView(server.params, cfg.d_model, device)
-    shape = reference.Shape.from_config(cell.config)
-    return check.compare(view, shape, seqs, tokens, cell.check["ref_rows"])
+    return check.compare(reference(cell, server.params, cfg.d_model, device),
+                         seqs, tokens, cell.check["ref_rows"])

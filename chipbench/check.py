@@ -2,7 +2,7 @@
 
 Once the window has closed, a sample of the requests it finished is drawn
 from the seed: half from the first half of the batch rows and half from
-the second, from any batch of the window.  The reference reruns each
+the second, from any batch of the window.  The family's reference reruns each
 sampled prompt with the tokens the program served and gives the logits
 at every position that produced a served token.  A served token's gap is
 how far its reference logit lies below the reference's best there; the
@@ -18,10 +18,14 @@ from __future__ import annotations
 
 from typing import Callable, List, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
-from chipbench import reference, traffic
+from chipbench import traffic
+
+# (tokens (n, S), first, precision) -> logits (n, S - first, vocab)
+Reference = Callable[..., jax.Array]
 
 
 def sample(seed: int, batches: int, batch: int, rows: int
@@ -44,21 +48,22 @@ def _gaps(ref_logits, tokens) -> np.ndarray:
     return np.asarray(best - got)
 
 
-def compare(weights, shape, seqs: np.ndarray, served: np.ndarray,
+def compare(logits: Reference, seqs: np.ndarray, served: np.ndarray,
             block: int, control: Sequence[str] = ()) -> dict:
     """Widest gaps of ``served`` (n, G+1) after prompts ``seqs`` (n, P+G)
     (each prompt followed by its served tokens but the last), and of each
-    ``control`` precision's own first choices at the same positions."""
+    ``control`` precision's own first choices at the same positions, by
+    the reference ``logits``."""
     first = seqs.shape[1] - served.shape[1]
     out = {"max_gap": 0.0}
     out.update({f"control.{p}": 0.0 for p in control})
     for i in range(0, len(seqs), block):
         rows = seqs[i:i + block]
-        ref = reference.logits(weights, shape, rows, first)
+        ref = logits(rows, first)
         out["max_gap"] = max(out["max_gap"],
                              float(_gaps(ref, served[i:i + block]).max()))
         for p in control:
-            low = reference.logits(weights, shape, rows, first, precision=p)
+            low = logits(rows, first, precision=p)
             chosen = np.asarray(jnp.argmax(low, -1))
             out[f"control.{p}"] = max(out[f"control.{p}"],
                                       float(_gaps(ref, chosen).max()))

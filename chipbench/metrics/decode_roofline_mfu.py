@@ -1,6 +1,6 @@
 """decode.roofline_mfu: the least time the traced decode steps need (each
 step the larger of its needed FLOPs over the bf16 peak and its needed
-bytes over HBM bandwidth, per chip; ``counts.decode`` charges the weights
+bytes over HBM bandwidth, per chip; the family's ``decode`` charges the weights
 without the embedding table and the cache up to its filled length) over
 the time the steps took, from dispatch until their tokens were on the
 host, in %."""

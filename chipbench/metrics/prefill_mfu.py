@@ -1,4 +1,4 @@
-"""prefill.mfu: the FLOPs prefill needs (``counts.prefill``: projections,
+"""prefill.mfu: the FLOPs prefill needs (the family's ``prefill``: projections,
 causal attention, the last position's lm_head) over the traced prefill
 spans' time times the chips' bf16 peak, in %."""
 

@@ -40,7 +40,7 @@ def rehearse(workload: str, topo) -> dict:
     from repro.serving.engine import make_serve_steps
 
     c = bench.load_cell(workload)
-    cfg = bench.model_config(c.config)
+    cfg = bench.model_config(c.config, c.family)
     mix = c.traffic
     B, P = mix["batch"], mix["prompt_tokens"]
     mesh = make_elastic_mesh(target_model=c.config["mesh"]["model"],
@@ -56,7 +56,7 @@ def rehearse(workload: str, topo) -> dict:
     batch = _placed(batch_abs, batch_sh)
     tok = jax.ShapeDtypeStruct((B, 1), jnp.int32, sharding=tok_sh)
     key = jax.eval_shape(lambda: weights.key_for(0, 0))
-    build = weights.builder(params_abs, cfg.d_model)
+    build = weights.builder(params_abs, cfg.d_model, c.family.WEIGHTS)
 
     programs = {
         "weights": jax.jit(build, out_shardings=param_sh).lower(key),

@@ -98,7 +98,7 @@ def run(workload: str, seed: int, seconds: float, t_start: float,
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     counter = cell._compile_counter()
     watch = WATCH.install()
-    cfg = bench.model_config(c.config)
+    cfg = bench.model_config(c.config, c.family)
     mix = c.traffic
     server = cell.Server(c, cfg, devices)
     server.load(seed, cfg.d_model)

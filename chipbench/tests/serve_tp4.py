@@ -35,7 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from chipbench import bench, check, reference, weights
+from chipbench import bench, check, weights
 from chipbench.cell import _abstract_params
 
 B, P, G = 2, 12, 5
@@ -98,19 +98,21 @@ def main() -> int:
         raise SystemExit(f"needs 4 devices (XLA_FLAGS="
                          f"--xla_force_host_platform_device_count=4), "
                          f"found {len(devices)}")
-    served = bench.model_config(config)
+    family = bench.family(config["family"])
+    served = bench.model_config(config, family)
     cfg = replace(served, dtype="float32", param_dtype="float32")
-    shape = reference.Shape.from_config(config)
+    shape = family.Shape.from_config(config)
     prompts = jnp.asarray(np.random.default_rng(SEED % 2**32).integers(
         0, cfg.vocab, (B, P)), jnp.int32)
 
     tp = _steps(cfg, devices, config["mesh"]["model"])
-    params = weights.make(tp.params_abs, tp.param_sh, SEED, cfg.d_model)
+    params = weights.make(tp.params_abs, tp.param_sh, SEED, cfg.d_model,
+                          family.WEIGHTS)
     logits = _serve(tp, params, prompts)
     fed = jnp.argmax(logits, -1)[:, :G]
     seqs = np.concatenate([np.asarray(prompts), np.asarray(fed)], 1)
-    view = weights.ReferenceView(params, cfg.d_model, devices[0])
-    ref = reference.logits(view, shape, seqs, P - 1)
+    view = family.ReferenceView(params, cfg.d_model, devices[0])
+    ref = family.logits(view, shape, seqs, P - 1)
 
     one = _steps(cfg, devices[:1], 1)
     one_logits = _serve(one, jax.device_put(params, one.param_sh), prompts,
@@ -127,13 +129,13 @@ def main() -> int:
 
     low = _steps(served, devices, config["mesh"]["model"])
     low_params = weights.make(low.params_abs, low.param_sh, SEED,
-                              cfg.d_model)
+                              cfg.d_model, family.WEIGHTS)
     low_logits = _serve(low, low_params, prompts)
     low_served = np.asarray(jnp.argmax(low_logits, -1))
     low_seqs = np.concatenate([np.asarray(prompts), low_served[:, :-1]], 1)
-    gap = check.compare(weights.ReferenceView(low_params, cfg.d_model,
-                                              devices[0]),
-                        shape, low_seqs, low_served, block=2)["max_gap"]
+    low_view = family.ReferenceView(low_params, cfg.d_model, devices[0])
+    gap = check.compare(partial(family.logits, low_view, shape), low_seqs,
+                        low_served, block=2)["max_gap"]
 
     from repro.obs.serving import collectives
 

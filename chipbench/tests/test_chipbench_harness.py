@@ -63,7 +63,8 @@ def test_config_that_contradicts_the_program_is_refused(root):
     c["intermediate_size"] = 512
     path.write_text(json.dumps(c))
     with pytest.raises(bench.SpecError, match="intermediate_size"):
-        bench.model_config(bench.load_cell(smoke_root.CELL, root).config)
+        c = bench.load_cell(smoke_root.CELL, root)
+        bench.model_config(c.config, c.family)
 
 
 def test_device_check_refuses_the_cpu():

@@ -1,8 +1,10 @@
-"""Weights made by the benchmark from the seed, and their published form.
+"""Weights made by the benchmark from the seed.
 
 ``make`` builds every parameter of the program's layout in one jitted
 call, on the device, in the dtype it is served in and with the program's
-shardings.  The program only gets to say the shapes and the layout.
+shardings.  The program only gets to say the shapes and the layout.  A
+family's ``WEIGHTS`` (leaf name -> rule) is asked first, for leaves of its
+own; every other leaf is made by the shared rules below.
 
 Scales are chosen so that every layer kind moves the logits, which is
 what lets the correctness check see a fault in any of them:
@@ -15,16 +17,13 @@ what lets the correctness check see a fault in any of them:
   nothing;
 - every other matrix is N(0, 1/fan_in); norm gains are 1 + N(0, 0.1^2).
 
-``ReferenceView`` converts to the published form the reference reads.
-The program multiplies its embedding rows by sqrt(d_model) (a Gemma
-convention that Phi-3 and Yi do not have), so its table holds the
-published table divided by sqrt(d_model), as a checkpoint converter for
-this program would store it, and the view multiplies it back.
+Each leaf draws from its own key, ``fold_in(key, i)`` with ``i`` its index
+in the tree's order, so a rule changes no other leaf's bytes.
 """
 from __future__ import annotations
 
 import math
-from typing import Mapping
+from typing import Callable, Mapping
 
 import jax
 import jax.numpy as jnp
@@ -44,9 +43,15 @@ def _leaf_name(path) -> str:
     return str(getattr(path[-1], "key", path[-1]))
 
 
-def _leaf(name: str, shape, dtype, key, d_model: int):
+Rule = Callable[..., jax.Array]  # (z, shape, d_model) -> float32 weight
+
+
+def _leaf(name: str, shape, dtype, key, d_model: int,
+          rules: Mapping[str, Rule]):
     z = jax.random.normal(key, shape, jnp.float32)
-    if name == "tok":
+    if name in rules:
+        w = rules[name](z, shape, d_model)
+    elif name == "tok":
         w = z / math.sqrt(d_model)
     elif name == "scale":
         w = 1.0 + NORM_NOISE * z
@@ -59,51 +64,24 @@ def _leaf(name: str, shape, dtype, key, d_model: int):
     return w.astype(dtype)
 
 
-def builder(params_abs, d_model: int):
+def builder(params_abs, d_model: int, rules: Mapping[str, Rule]):
     """A function of a key that makes every weight of ``params_abs``
-    (shapes and dtypes)."""
+    (shapes and dtypes), asking the family's ``rules`` first."""
     flat, treedef = jax.tree_util.tree_flatten_with_path(params_abs)
 
     def build(key):
         return treedef.unflatten([
             _leaf(_leaf_name(path), leaf.shape, leaf.dtype,
-                  jax.random.fold_in(key, i), d_model)
+                  jax.random.fold_in(key, i), d_model, rules)
             for i, (path, leaf) in enumerate(flat)])
 
     return build
 
 
-def make(params_abs, shardings, seed: int, d_model: int):
+def make(params_abs, shardings, seed: int, d_model: int,
+         rules: Mapping[str, Rule]):
     """Every weight from ``seed``, in one jitted call whose outputs carry
     ``shardings``."""
-    build = builder(params_abs, d_model)
+    build = builder(params_abs, d_model, rules)
     return jax.jit(build, out_shardings=shardings)(key_for(seed, 0))
 
-
-class ReferenceView:
-    """The program's weights in the published form, one layer at a time
-    on ``device``: what a checkpoint of the published model would hold."""
-
-    def __init__(self, params: Mapping, d_model: int, device):
-        self._p = params
-        self._layers = params["groups"][0][0]
-        self._scale = math.sqrt(d_model)
-        self._dev = device
-        self.final_norm = self._put(params["final_norm"]["scale"])
-        self.lm_head = self._put(params["lm_head"])
-
-    def _put(self, x):
-        return jax.device_put(x, self._dev)
-
-    def embed_rows(self, tokens):
-        rows = jnp.take(self._p["embed"]["tok"], jnp.asarray(tokens), axis=0)
-        return self._put(rows).astype(jnp.float32) * self._scale
-
-    def layer(self, i: int) -> dict:
-        p = self._layers
-        return {k: self._put(v[i]) for k, v in (
-            ("attn_norm", p["ln1"]["scale"]), ("wq", p["attn"]["wq"]),
-            ("wk", p["attn"]["wk"]), ("wv", p["attn"]["wv"]),
-            ("wo", p["attn"]["wo"]), ("mlp_norm", p["ln2"]["scale"]),
-            ("w_gate", p["mlp"]["wg"]), ("w_up", p["mlp"]["wu"]),
-            ("w_down", p["mlp"]["wd"]))}
